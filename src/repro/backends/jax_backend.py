@@ -22,6 +22,7 @@ implement at scale (see ``launch/sharding.py``).
 from __future__ import annotations
 
 import time
+import weakref
 from typing import Any, Mapping
 
 from repro.core.compile import StepMeta
@@ -46,6 +47,22 @@ def _is_array(x: Any) -> bool:
     import numpy as np
 
     return isinstance(x, (jax.Array, np.ndarray))
+
+
+def _buffer_ptrs(x: Any) -> frozenset[int]:
+    """Addresses of the memory an array payload occupies."""
+    import jax
+    import numpy as np
+
+    if isinstance(x, jax.Array):
+        if x.is_deleted():
+            return frozenset()
+        return frozenset(
+            s.data.unsafe_buffer_pointer() for s in x.addressable_shards
+        )
+    if isinstance(x, np.ndarray):
+        return frozenset({x.ctypes.data})
+    return frozenset()
 
 
 def _plan_segments(program, *, min_len: int = 2) -> dict[int, list]:
@@ -111,9 +128,9 @@ class _FusedSegment:
     run's step bodies and returns every datum the run produces, so the
     per-location stores a fused run leaves behind are identical to the
     interpreted ones.  The env is split into ``(donated, kept)`` dicts:
-    inputs the segment overwrites and that no other store entry aliases
-    are donated so XLA can reuse their buffers in place (donation is
-    skipped on CPU where the runtime does not support it).
+    inputs the segment overwrites are donated so XLA can reuse their
+    buffers in place, but only buffers the backend itself allocated and
+    that no other store entry shares (see ``JaxMeshProgram.run``).
     """
 
     def __init__(self, acts: list, steps: Mapping[str, StepMeta]):
@@ -153,9 +170,15 @@ class _FusedSegment:
             return {d: env[d] for d in out_names}
 
         self.fn = jax.jit(seg_fn, donate_argnums=(0,))
+        self.traced = False
+        self.arg_shapes: Any = None  # avals of the last call, for hlo()
         self.calls = 0
         self.seconds = 0.0  # warm (post-compile) call time only
         self.bytes = 0
+
+    def hlo(self) -> str:
+        """Compiled HLO text of the segment for its last call's arguments."""
+        return self.fn.lower(*self.arg_shapes).compile().as_text()
 
 
 class JaxMeshProgram(BackendProgram):
@@ -215,12 +238,26 @@ class JaxMeshProgram(BackendProgram):
             deadline = Deadline(policy.deadline_s)
             stats["policy"] = {"retries": 0, "timeouts": 0}
 
+        # Buffers this run allocated itself.  Only these may be donated:
+        # a caller's initial payload, or an array a step body returned
+        # (which it may still hold), must outlive the run.  ``device_put``
+        # onto the array's own device shares its buffer, so a copy is
+        # owned only if it landed in new memory.
+        owned: weakref.WeakValueDictionary = weakref.WeakValueDictionary()
+
+        def own(value: Any) -> Any:
+            owned[id(value)] = value
+            return value
+
         def place(loc: str, value: Any) -> Any:
             if not _is_array(value):
                 return value
             stats["device_puts"] += 1
             stats["bytes_moved"] += int(getattr(value, "nbytes", 0))
-            return jax.device_put(value, device_of[loc])
+            out = jax.device_put(value, device_of[loc])
+            if not _buffer_ptrs(out) & _buffer_ptrs(value):
+                own(out)
+            return out
 
         payloads: dict[PayloadKey, Any] = {}
         for (loc, d), v in (initial_payloads or {}).items():
@@ -273,9 +310,28 @@ class JaxMeshProgram(BackendProgram):
                 "fused_calls": 0,
                 "fused_execs": 0,
                 "fallbacks": 0,
+                "donated": 0,
                 "locations": {},
             }
         exec_count = 0
+
+        def donatable(seg: _FusedSegment, env: dict) -> dict[str, Any]:
+            """Inputs whose buffers the segment may consume in place."""
+            cand = {
+                d: env[d] for d in seg.donatable
+                if owned.get(id(env[d])) is env[d]
+            }
+            if not cand:
+                return {}
+            shared: dict[int, int] = {}
+            for v in payloads.values():
+                for ptr in _buffer_ptrs(v):
+                    shared[ptr] = shared.get(ptr, 0) + 1
+            # The candidate's own store entry is its one reference.
+            return {
+                d: v for d, v in cand.items()
+                if all(shared.get(ptr, 0) == 1 for ptr in _buffer_ptrs(v))
+            }
 
         def run_segment(start: int) -> bool:
             """Fire a whole planned segment as one jitted call.
@@ -283,7 +339,8 @@ class JaxMeshProgram(BackendProgram):
             Returns False (after caching the verdict) when the segment
             must stay interpreted — non-array inputs, or a step body
             that does not trace; the caller then falls through to the
-            op-by-op path for every op in the run.
+            op-by-op path for every op in the run.  An error while
+            compiling or running a segment that did trace propagates.
             """
             import time as _time
 
@@ -299,29 +356,29 @@ class JaxMeshProgram(BackendProgram):
                 seg_cache[start] = "eager"
                 stats["fused"]["fallbacks"] += 1
                 return False
-            donated: dict[str, Any] = {}
-            platform = getattr(device_of[seg.leader], "platform", "cpu")
-            if platform != "cpu":
-                for d in seg.donatable:
-                    v = env[d]
-                    if all(
-                        d2 == d and l2 == seg.leader
-                        for (l2, d2), v2 in payloads.items()
-                        if v2 is v
-                    ):
-                        donated[d] = v
+            donated = donatable(seg, env)
             kept = {d: v for d, v in env.items() if d not in donated}
+            if not seg.traced:
+                try:
+                    seg.fn.trace(donated, kept)
+                except Exception:  # a step body that does not trace
+                    seg_cache[start] = "eager"
+                    stats["fused"]["fallbacks"] += 1
+                    return False
+                seg.traced = True
+            stats["fused"]["donated"] += len(donated)
             first_call = seg.calls == 0
-            try:
-                import jax
-
-                t0 = _time.perf_counter()
-                out = jax.block_until_ready(seg.fn(donated, kept))
-                dt = _time.perf_counter() - t0
-            except Exception:  # not traceable / unsupported payloads
-                seg_cache[start] = "eager"
-                stats["fused"]["fallbacks"] += 1
-                return False
+            seg.arg_shapes = jax.tree.map(
+                lambda v: jax.ShapeDtypeStruct(
+                    v.shape, v.dtype, sharding=getattr(v, "sharding", None)
+                ),
+                (donated, kept),
+            )
+            t0 = _time.perf_counter()
+            out = jax.block_until_ready(seg.fn(donated, kept))
+            dt = _time.perf_counter() - t0
+            for v in out.values():
+                own(v)
             seg.calls += 1
             moved = sum(
                 int(getattr(v, "nbytes", 0)) for v in env.values()
@@ -420,17 +477,26 @@ class JaxMeshProgram(BackendProgram):
                 break
 
         if fuse:
-            from repro.roofline import HBM_BW
+            from repro.roofline import device_peaks
 
+            # Host-clock bandwidth of warm fused calls against the HBM
+            # peak of the device that ran them; a device kind without a
+            # published peak gets no entry.
             roofline = {}
             for loc, ls in stats["fused"]["locations"].items():
+                peaks = device_peaks(
+                    getattr(device_of[loc], "device_kind", None)
+                )
+                if peaks is None:
+                    continue
                 achieved = (
                     ls["bytes"] / ls["seconds"] if ls["seconds"] > 0 else 0.0
                 )
                 roofline[loc] = {
+                    "device_kind": device_of[loc].device_kind,
                     "achieved_bytes_per_s": achieved,
-                    "theoretical_bytes_per_s": HBM_BW,
-                    "fraction_of_roof": achieved / HBM_BW,
+                    "theoretical_bytes_per_s": peaks.hbm_bytes_per_s,
+                    "fraction_of_roof": achieved / peaks.hbm_bytes_per_s,
                 }
             stats["fused"]["roofline"] = roofline
         if guard is not None:
@@ -457,6 +523,21 @@ class JaxMeshProgram(BackendProgram):
         return ExecutionResult(
             backend="jax", data=result, stats=stats, profile=profile
         )
+
+    def segment_hlo(self) -> dict[int, str]:
+        """Compiled HLO text of every fused segment that has run.
+
+        Keyed by the segment's first exec index in the firing order;
+        shows which kernels the compiler emitted for the device the
+        segment ran on (a Pallas kernel on a TPU is a
+        ``tpu_custom_call``).
+        """
+        cache = getattr(self, "_seg_cache", {})
+        return {
+            start: seg.hlo()
+            for start, seg in sorted(cache.items())
+            if isinstance(seg, _FusedSegment) and seg.calls
+        }
 
 
 class JaxBackend(Backend):

@@ -45,18 +45,32 @@ harmless.
 Requirements: the default start method is ``fork`` (closures and lambdas
 work as step functions); with ``start_method="spawn"`` every step function
 and payload must be picklable.
+
+Accelerators
+------------
+A process that has opened a TPU holds it until it exits.  A forked child
+would inherit that device client in a state it cannot use, and a fresh
+process cannot open the chip.  So once the coordinator holds an
+accelerator, workers are spawned fresh with JAX restricted to the CPU
+(``JAX_PLATFORMS=cpu``): their step functions must then be picklable,
+``jax.Array`` payloads reach them as CPU arrays, and their results come
+back onto the coordinator's default device.  ``start_method="fork"`` and
+unpicklable step functions are refused up front with
+:class:`AcceleratorHeldError`.
 """
 
 from __future__ import annotations
 
 import multiprocessing as mp
 import os
+import pickle
 import shutil
 import signal
 import tempfile
 import threading
 import time
 import warnings
+from contextlib import contextmanager
 from dataclasses import replace
 from types import SimpleNamespace
 from typing import Any, Mapping, Sequence
@@ -66,9 +80,39 @@ from repro.core.parser import dumps
 from repro.core.syntax import Exec, WorkflowSystem, actions
 from repro.exec.program import ExecProgram, LocationProgram
 
-from .base import Backend, BackendProgram, ExecutionResult, PayloadKey
+from .base import (
+    Backend,
+    BackendCapabilityError,
+    BackendProgram,
+    ExecutionResult,
+    PayloadKey,
+)
 
 DEFAULT_TIMEOUT_S = 120.0
+
+
+class AcceleratorHeldError(BackendCapabilityError):
+    """Workers cannot start as asked because this process holds an
+    accelerator (see the module's "Accelerators" section)."""
+
+
+def held_accelerator() -> str | None:
+    """Platform of a non-CPU JAX backend this process has opened, if any.
+
+    Reads JAX's backend registry without initialising it: a process that
+    has not used JAX, or only its CPU backend, holds no accelerator.
+    """
+    import sys
+
+    if "jax" not in sys.modules:
+        return None
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return None
+    return next(
+        (name for name in xla_bridge.backends() if name != "cpu"), None
+    )
 
 
 class WorkerFailedError(RuntimeError):
@@ -223,6 +267,32 @@ def _recorded_outputs(program: ExecProgram, ckpt: Any) -> dict[str, dict]:
 
 
 _MISSING = object()
+
+
+@contextmanager
+def _worker_env(held: str | None):
+    """The environment a worker is started in.
+
+    Spawned while this process holds an accelerator: JAX restricted to the
+    CPU, so a worker never tries to open the chip.  Forked from a process
+    that holds none: JAX's fork warning is silenced.  Step functions that
+    call JAX in such a worker need a parent that has not run JAX itself,
+    or ``start_method="spawn"``: a forked copy of JAX's CPU client aborts.
+    """
+    if held is None:
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore")
+            yield
+        return
+    before = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        yield
+    finally:
+        if before is None:
+            del os.environ["JAX_PLATFORMS"]
+        else:
+            os.environ["JAX_PLATFORMS"] = before
 
 
 # ---------------------------------------------------------------------------
@@ -469,7 +539,26 @@ class MultiprocessProgram(BackendProgram):
                 "boundaries; the multiprocess backend needs one that can "
                 '(e.g. "socket")'
             )
-        if start_method is None:
+        held = held_accelerator()
+        if held is not None:
+            if start_method == "fork":
+                raise AcceleratorHeldError(
+                    f"this process holds a {held} device; forked workers "
+                    "would inherit its client in a state they cannot use "
+                    '(use the default start method, "spawn" here)'
+                )
+            start_method = "spawn"
+            for name, meta in self.steps.items():
+                try:
+                    pickle.dumps(meta.fn)
+                except (pickle.PicklingError, AttributeError, TypeError) as e:
+                    raise AcceleratorHeldError(
+                        f"this process holds a {held} device, so workers "
+                        "are spawned fresh with JAX on the CPU, and step "
+                        f"{name!r} cannot be pickled for them ({e}); "
+                        "define step functions at module level"
+                    ) from e
+        elif start_method is None:
             start_method = (
                 "fork"
                 if "fork" in mp.get_all_start_methods()
@@ -517,6 +606,7 @@ class MultiprocessProgram(BackendProgram):
                 recorded,
                 groups=groups,
                 ctx=ctx,
+                held=held,
                 transport_name=transport_name,
                 timeout_s=(
                     timeout_s if rem is None
@@ -668,6 +758,7 @@ class MultiprocessProgram(BackendProgram):
         *,
         groups: list[tuple[str, ...]],
         ctx,
+        held: str | None,
         transport_name: str,
         timeout_s: float,
         ack_timeout: float,
@@ -815,10 +906,7 @@ class MultiprocessProgram(BackendProgram):
                     name=f"swirl-worker-{wid}",
                     daemon=True,
                 )
-                with warnings.catch_warnings():
-                    # Forking a process that imported a multithreaded
-                    # library (jax) warns; workers only run pure Python.
-                    warnings.simplefilter("ignore")
+                with _worker_env(held):
                     proc.start()
                 child.close()
                 procs.append(proc)

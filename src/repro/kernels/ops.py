@@ -1,8 +1,9 @@
 """Jitted public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode; on a
-real TPU platform they compile to Mosaic.  The interpret switch is decided
-once per process from the default backend.
+On a TPU the kernels compile to Mosaic.  On the CPU platform they run in
+Pallas interpret mode, which the CPU tests also request explicitly with
+``interpret=True``.  Any other platform is an error: a kernel never falls
+back to the interpreter where a device was expected.
 """
 
 from __future__ import annotations
@@ -17,7 +18,15 @@ from .rmsnorm import rmsnorm as _rmsnorm
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas TPU kernels cannot run on platform {platform!r}; "
+        "pass interpret=True to run them in the Pallas interpreter"
+    )
 
 
 @partial(

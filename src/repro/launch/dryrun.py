@@ -1,11 +1,14 @@
 import os
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
-The two lines above MUST stay first: JAX locks the host device count on
-first init, and the production meshes need 512 placeholder devices.
+The lines above MUST stay first: JAX locks the host device count on
+first init, and the production meshes need 512 placeholder devices.  The
+placeholders are CPU devices: on a TPU host the dry-run (and every child
+of its fleet mode) would otherwise open the chip.
 
 Single-cell mode (one compile per process — compile memory is bounded)::
 

@@ -22,12 +22,20 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
-    """Arbitrary mesh (elastic restarts build degraded meshes through this)."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh (elastic restarts build degraded meshes through this).
+
+    Every axis is ``Auto``: GSPMD propagates shardings and the model's
+    hints (``with_sharding_constraint``, ``shard_map``) refine them.
+    ``jax.make_mesh`` otherwise defaults to ``Explicit`` axes, under which
+    those constraints are refused.
+    """
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def data_axes(mesh: jax.sharding.Mesh) -> tuple[str, ...]:

@@ -12,14 +12,20 @@ Cross-pod gradient traffic goes through int8 error-feedback compression
 (:mod:`repro.optim.compress`) — the explicit send/recv structure of the
 SWIRL plan is what makes the compression insertion point well-defined.
 
-CPU-offline note: all "pods" share this host's device; the orchestration
-path (plans, channels, checkpoints, recovery) is identical to the
-multi-controller deployment, where each pod process executes its own trace.
+All "pods" run in this one process and share its default device: the
+host under ``JAX_PLATFORMS=cpu``, or the one chip of a TPU host, where
+every pod's step bodies run on that chip.  The orchestration path (plans,
+channels, checkpoints, recovery) is the one a multi-controller deployment
+runs, where each pod process executes its own trace.
 
 Usage::
 
     PYTHONPATH=src python -m repro.launch.train --arch llama3.2-3b --smoke \
         --steps 20 --pods 2 --global-batch 8 --seq-len 64
+
+``train()`` also takes a :class:`~repro.models.ModelConfig` in place of the
+arch name, e.g. a published config cut in depth with
+``dataclasses.replace`` (``chip_smoke.py`` does this).
 """
 
 from __future__ import annotations
@@ -36,12 +42,13 @@ from repro import swirl
 from repro.configs import get_config
 from repro.core.translate import TrainPipelineTranslator
 from repro.data import SyntheticLM
-from repro.models import Model
+from repro.models import Model, ModelConfig
 from repro.optim import AdamWConfig
 from repro.optim import adamw as adamw_mod
 from repro.optim.compress import allreduce_mean, compress, decompress
 from repro.workflow import RetryPolicy
 from repro.ckpt import async_save, latest_step, load_checkpoint
+from .cache import configure_compile_cache
 from .steps import make_grad_step
 
 PyTree = Any
@@ -97,12 +104,19 @@ def build_step_fns(
 
     def gradsync(inputs):
         parts = []
-        metrics = {}
+        pod_metrics = []
         for i in range(n_pods):
             (kind, payload), metrics = inputs[f"grad_{i}"]
             parts.append(decompress(payload) if kind == "int8" else payload)
+            pod_metrics.append(metrics)
         mean = allreduce_mean(parts)
-        return {"grad_sync": (mean, {k: float(v) for k, v in metrics.items()})}
+        # Pods hold equal shards, so the mean of their losses is the
+        # step's loss over the global batch (per-pod router aux terms).
+        metrics = {
+            k: sum(float(m[k]) for m in pod_metrics) / n_pods
+            for k in pod_metrics[0]
+        }
+        return {"grad_sync": (mean, metrics)}
 
     def ckpt(inputs):
         state = inputs["state_0"]
@@ -121,9 +135,9 @@ def build_step_fns(
 
 
 def train(
-    arch: str,
+    arch: str | ModelConfig,
     *,
-    smoke: bool,
+    smoke: bool = False,
     steps: int,
     n_pods: int,
     global_batch: int,
@@ -132,7 +146,7 @@ def train(
     compress_grads: bool = True,
     log_every: int = 5,
 ) -> dict:
-    cfg = get_config(arch, smoke=smoke)
+    cfg = arch if isinstance(arch, ModelConfig) else get_config(arch, smoke=smoke)
     model = Model(cfg)
     dataset = SyntheticLM(
         vocab=cfg.vocab, seq_len=seq_len, global_batch=global_batch
@@ -168,6 +182,7 @@ def train(
 
     err: dict[int, PyTree] = {}
     history = []
+    retries = 0
     grad_fn = jax.jit(make_grad_step(model))
     update_fn = jax.jit(partial(adamw_mod.update, opt_cfg))
     t0 = time.monotonic()
@@ -185,7 +200,11 @@ def train(
         # ``shard_i``/``fwdbwd_i`` read iter/params from the pod's local data
         # scope: declare them as part of each pod's initial D set.
         result = lowered.compile(fns).run(initial_payloads=payloads)
+        retries += result.stats.retries
         state = result.payload("pod0", "state_0")
+        # Free this iteration's other pods' states, gradients and means
+        # (and the previous params) before the next run allocates its own.
+        del result, payloads
         params, opt_state = state["params"], state["opt"]
         m = state["metrics"]
         history.append(m)
@@ -195,8 +214,14 @@ def train(
                 f"gnorm={m.get('grad_norm', 0):.3f}"
             )
     wall = time.monotonic() - t0
-    print(f"[done] {steps} steps in {wall:.1f}s ({wall / steps:.2f}s/step)")
-    return {"history": history, "params": params, "opt": opt_state}
+    print(
+        f"[done] {steps} steps in {wall:.1f}s ({wall / steps:.2f}s/step), "
+        f"{retries} step retries"
+    )
+    return {
+        "history": history, "params": params, "opt": opt_state,
+        "retries": retries,
+    }
 
 
 def main() -> None:
@@ -211,6 +236,7 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--no-compress", dest="compress", action="store_false")
     args = ap.parse_args()
+    configure_compile_cache()
     train(
         args.arch,
         smoke=args.smoke,

@@ -17,7 +17,6 @@ step can add ``router_aux_weight ×`` it.
 
 from __future__ import annotations
 
-import inspect
 import math
 from typing import Any
 
@@ -221,10 +220,7 @@ def apply_moe_sharded(
     cfg: ModelConfig, p: Params, x: jax.Array
 ) -> tuple[jax.Array, jax.Array]:
     """Expert-local MoE dispatch (requires installed ShardHints)."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax exposes it under experimental
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from .hints import get_hints
@@ -269,12 +265,7 @@ def apply_moe_sharded(
             P(dp, tp if scatter_out else None, None),
             P(),
         ),
-        # replication checking was renamed check_rep -> check_vma
-        **(
-            {"check_vma": False}
-            if "check_vma" in inspect.signature(shard_map).parameters
-            else {"check_rep": False}
-        ),
+        check_vma=False,
     )
     return fn(
         x, p["router"]["w"], p["gate"], p["up"], p["down"], shared

@@ -19,9 +19,40 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # bytes/s / chip
-ICI_BW = 50e9  # bytes/s / link
+
+@dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one accelerator kind."""
+
+    bf16_flops: float  # FLOP/s
+    hbm_bytes_per_s: float
+    ici_bytes_per_s: float  # per link
+    source: str
+
+
+# Keyed by ``jax.Device.device_kind``.  A device that is not here has no
+# peak: callers report nothing for it rather than borrow another's.
+DEVICE_PEAKS: dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(
+        bf16_flops=197e12,
+        hbm_bytes_per_s=819e9,
+        # 1,600 Gbit/s of interchip interconnect over four links
+        ici_bytes_per_s=50e9,
+        source="Google Cloud documentation, 'TPU v5e' system architecture",
+    ),
+}
+
+
+def device_peaks(device_kind: str | None) -> DevicePeaks | None:
+    """Peaks of ``device_kind`` from :data:`DEVICE_PEAKS`, or ``None``."""
+    return DEVICE_PEAKS.get(device_kind or "")
+
+
+# The dry-run's three-term model targets the v5e production mesh.
+_V5E = DEVICE_PEAKS["TPU v5 lite"]
+PEAK_FLOPS = _V5E.bf16_flops  # bf16 / chip
+HBM_BW = _V5E.hbm_bytes_per_s  # bytes/s / chip
+ICI_BW = _V5E.ici_bytes_per_s  # bytes/s / link
 
 
 @dataclass(frozen=True)

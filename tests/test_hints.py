@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.models import Model, ModelConfig, MoECfg
 from repro.models.hints import ShardHints, get_hints, set_hints
 from repro.models.layers import sdpa
@@ -14,7 +15,7 @@ from repro.models.layers import sdpa
 
 @pytest.fixture
 def unit_mesh():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     set_hints(ShardHints(mesh=mesh, dp_axes=("data",)))
     yield mesh
     set_hints(None)

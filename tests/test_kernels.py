@@ -161,3 +161,18 @@ def test_ops_wrappers_model_layout():
         v.transpose(0, 2, 1, 3), causal=True,
     ).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+
+
+@pytest.mark.parametrize(
+    "platform,interpret", [("cpu", True), ("tpu", False), ("gpu", None)]
+)
+def test_ops_interpret_follows_platform(monkeypatch, platform, interpret):
+    """Interpret mode only on the CPU; no other device falls back to it."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: platform)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="cannot run on platform"):
+            ops._interpret_default()
+    else:
+        assert ops._interpret_default() is interpret

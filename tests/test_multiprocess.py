@@ -213,6 +213,37 @@ class TestWorkerAssignment:
             plan.lower("multiprocess", warp_speed=True)
 
 
+class TestParentHoldingAccelerator:
+    """A coordinator that holds a TPU never forks its workers."""
+
+    def test_cpu_only_process_holds_no_accelerator(self):
+        from repro.backends.multiprocess import held_accelerator
+
+        assert held_accelerator() is None
+
+    def test_fork_refused(self, plan, monkeypatch):
+        from repro.backends import multiprocess as mpb
+
+        monkeypatch.setattr(mpb, "held_accelerator", lambda: "tpu")
+        exe = plan.lower("multiprocess", start_method="fork").compile(
+            quickstart_steps()
+        )
+        with pytest.raises(mpb.AcceleratorHeldError, match="holds a tpu"):
+            exe.run()
+        assert not mp.active_children()
+
+    def test_unpicklable_steps_refused_before_any_worker(
+        self, plan, monkeypatch
+    ):
+        from repro.backends import multiprocess as mpb
+
+        monkeypatch.setattr(mpb, "held_accelerator", lambda: "tpu")
+        exe = plan.lower("multiprocess").compile(quickstart_steps())
+        with pytest.raises(mpb.AcceleratorHeldError, match="cannot be pickled"):
+            exe.run()
+        assert not mp.active_children()
+
+
 # ---------------------------------------------------------------------------
 # Fault injection: worker death, orphan hygiene, checkpoint/restore
 # ---------------------------------------------------------------------------

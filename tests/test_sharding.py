@@ -15,17 +15,8 @@ from repro.launch.sharding import batch_specs, cache_specs, param_specs
 from repro.launch.steps import abstract_cache, abstract_params, input_specs
 from repro.models import Model
 
-def _abstract_mesh(sizes, names):
-    """AbstractMesh across jax versions: old API takes (name, size) pairs,
-    new API takes (sizes, names) positionally."""
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
-MESH1 = _abstract_mesh((16, 16), ("data", "model"))
-MESH2 = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH1 = AbstractMesh((16, 16), ("data", "model"))
+MESH2 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _check_divisible(shapes, specs, mesh, where):

@@ -41,8 +41,9 @@ def test_checkpoint_written_and_resumes(short_run):
 def test_pods_agree_with_single_pod():
     """2-pod SWIRL plan ≡ 1-pod plan (data-parallel correctness): the
     *parameters* after the same number of steps must match — the logged
-    per-pod loss is each pod's local half-batch CE and legitimately
-    differs.  Compression disabled (int8 adds tiny per-pod noise)."""
+    loss is the mean of the pods' losses, whose router aux terms are
+    per pod, so it legitimately differs.  Compression disabled (int8 adds
+    tiny per-pod noise)."""
     import jax
 
     a = train(
