@@ -1,0 +1,10 @@
+"""Puts the chip benchmark's package on the path for its tests."""
+
+from __future__ import annotations
+
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[2] / "benchmarks" / "chip"
+if str(BENCH) not in sys.path:
+    sys.path.insert(0, str(BENCH))
