@@ -59,6 +59,7 @@ from repro.core.optimizer import REWRITE_RULES, OptimizationStats
 from repro.core.parser import parse_system
 from repro.core.syntax import Exec, WorkflowSystem, actions
 from repro.core.translate import DagTranslator, SWIRLTranslator
+from repro.obs.events import profiler_span
 from repro.sched import CostModel, NetworkModel, SizeModel, auto_placement
 from repro.sched.report import ScheduleReport
 
@@ -760,7 +761,14 @@ class Lowered:
 
         ``steps`` must cover every exec predicate in the plan; extra
         entries are ignored (one registry can serve many plans).
+        Runs inside a ``swirl.compile`` profiler span.
         """
+        with profiler_span("swirl.compile"):
+            return self._compile(steps)
+
+    def _compile(
+        self, steps: Mapping[str, StepFn | StepMeta]
+    ) -> "Executable":
         metas: dict[str, StepMeta] = {}
         needed = set(self.plan.steps())
         missing = needed - set(steps)

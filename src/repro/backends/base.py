@@ -27,10 +27,11 @@ entry-point group declared in ``pyproject.toml``.
 
 from __future__ import annotations
 
+import itertools
 from abc import ABC, abstractmethod
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
-from typing import Any, Mapping, Sequence
+from typing import Any, Iterator, Mapping, Sequence
 
 from repro.core.compile import StepMeta
 from repro.core.syntax import WorkflowSystem
@@ -88,6 +89,11 @@ class BackendProgram(ABC):
     program: ExecProgram
     steps: Mapping[str, StepMeta]
     options: dict[str, Any] = field(default_factory=dict)
+    #: Numbers this program's runs (``next()`` is atomic), for the ``run``
+    #: argument of their ``swirl.run`` profiler spans.
+    run_ids: Iterator[int] = field(
+        default_factory=itertools.count, init=False, repr=False, compare=False
+    )
 
     @property
     def system(self) -> WorkflowSystem:

@@ -54,6 +54,7 @@ class InprocessProgram(BackendProgram):
             dict(self.steps),
             initial_payloads=initial_payloads,
             completed=completed,
+            run_id=next(self.run_ids),
             **kwargs,
         )
 

@@ -31,11 +31,13 @@ from repro.exec.interp import (
     Cursor,
     Deadline,
     StepGuard,
+    comm_span,
     enabled_exec_picks,
+    exec_span,
     first_enabled_comm,
-    record_comm_fire,
-    record_exec_fire,
     record_policy_fire,
+    run_span,
+    segment_span,
 )
 from repro.exec.program import ExecProgram
 
@@ -137,7 +139,7 @@ class _FusedSegment:
         import jax
 
         self.acts = acts  # [(ExecOp, picks), ...] in firing order
-        ops = [op for op, _ in acts]
+        self.ops = ops = [op for op, _ in acts]
         self.leader = min(ops[0].locations)
         produced: set[str] = set()
         ext: list[str] = []
@@ -173,8 +175,6 @@ class _FusedSegment:
         self.traced = False
         self.arg_shapes: Any = None  # avals of the last call, for hlo()
         self.calls = 0
-        self.seconds = 0.0  # warm (post-compile) call time only
-        self.bytes = 0
 
     def hlo(self) -> str:
         """Compiled HLO text of the segment for its last call's arguments."""
@@ -206,6 +206,12 @@ class JaxMeshProgram(BackendProgram):
     def run(
         self, initial_payloads: Mapping[PayloadKey, Any] | None = None
     ) -> ExecutionResult:
+        with run_span("jax", next(self.run_ids)):
+            return self._run(initial_payloads)
+
+    def _run(
+        self, initial_payloads: Mapping[PayloadKey, Any] | None
+    ) -> ExecutionResult:
         import jax
 
         recorder = None
@@ -214,11 +220,16 @@ class JaxMeshProgram(BackendProgram):
 
             recorder = TraceRecorder()
         device_of = self._device_map()
+        # ``bytes_moved`` counts every array placement, including the
+        # same-device ``device_put``s that share their buffer and copy
+        # nothing; ``comm_bytes`` counts only COMM payloads that landed in
+        # new memory on another device.
         stats = {
             "execs": 0,
             "comms": 0,
             "device_puts": 0,
             "bytes_moved": 0,
+            "comm_bytes": 0,
             "devices": {l: str(d) for l, d in device_of.items()},
         }
         # Uniform fault policy: the deterministic reducer guards each step
@@ -249,14 +260,17 @@ class JaxMeshProgram(BackendProgram):
             owned[id(value)] = value
             return value
 
-        def place(loc: str, value: Any) -> Any:
+        def place(loc: str, value: Any, *, comm: bool = False) -> Any:
             if not _is_array(value):
                 return value
+            nbytes = int(getattr(value, "nbytes", 0))
             stats["device_puts"] += 1
-            stats["bytes_moved"] += int(getattr(value, "nbytes", 0))
+            stats["bytes_moved"] += nbytes
             out = jax.device_put(value, device_of[loc])
             if not _buffer_ptrs(out) & _buffer_ptrs(value):
                 own(out)
+                if comm:
+                    stats["comm_bytes"] += nbytes
             return out
 
         payloads: dict[PayloadKey, Any] = {}
@@ -277,16 +291,10 @@ class JaxMeshProgram(BackendProgram):
             cursors[src].complete(i)
             cursors[op.dst].complete(j)
             data[op.dst].add(op.data)
-            if recorder is None:
+            payload = payloads[(op.src, op.data)]
+            with comm_span(recorder, op, payload):
                 payloads[(op.dst, op.data)] = place(
-                    op.dst, payloads[(op.src, op.data)]
-                )
-            else:
-                payload = payloads[(op.src, op.data)]
-                t0 = time.monotonic()
-                payloads[(op.dst, op.data)] = place(op.dst, payload)
-                record_comm_fire(
-                    recorder, op, t0, time.monotonic(), payload
+                    op.dst, payload, comm=True
                 )
             stats["comms"] += 1
             return True
@@ -298,8 +306,7 @@ class JaxMeshProgram(BackendProgram):
         fuse = bool(self.options.get("fuse")) and guard is None
         if fuse and not hasattr(self, "_segments"):
             # Plan once per compiled program; jitted segment functions
-            # live across run() calls so repeat runs hit XLA's cache
-            # (and warm-call bandwidth is what roofline reports).
+            # live across run() calls so repeat runs hit XLA's cache.
             self._segments = _plan_segments(self.program)
             self._seg_cache: dict[int, Any] = {}
         segments = self._segments if fuse else {}
@@ -342,8 +349,6 @@ class JaxMeshProgram(BackendProgram):
             op-by-op path for every op in the run.  An error while
             compiling or running a segment that did trace propagates.
             """
-            import time as _time
-
             seg = seg_cache.get(start)
             if seg == "eager":
                 return False
@@ -367,36 +372,22 @@ class JaxMeshProgram(BackendProgram):
                     return False
                 seg.traced = True
             stats["fused"]["donated"] += len(donated)
-            first_call = seg.calls == 0
             seg.arg_shapes = jax.tree.map(
                 lambda v: jax.ShapeDtypeStruct(
                     v.shape, v.dtype, sharding=getattr(v, "sharding", None)
                 ),
                 (donated, kept),
             )
-            t0 = _time.perf_counter()
-            out = jax.block_until_ready(seg.fn(donated, kept))
-            dt = _time.perf_counter() - t0
+            with segment_span(recorder, seg.leader, seg.ops):
+                out = jax.block_until_ready(seg.fn(donated, kept))
             for v in out.values():
                 own(v)
             seg.calls += 1
-            moved = sum(
-                int(getattr(v, "nbytes", 0)) for v in env.values()
-            ) + sum(int(getattr(v, "nbytes", 0)) for v in out.values())
-            if not first_call:
-                # First call pays tracing + XLA compile; only warm calls
-                # count toward achieved-bandwidth reporting.
-                seg.seconds += dt
-                seg.bytes += moved
             loc_stats = stats["fused"]["locations"].setdefault(
-                seg.leader,
-                {"calls": 0, "execs": 0, "bytes": 0, "seconds": 0.0},
+                seg.leader, {"calls": 0, "execs": 0}
             )
             loc_stats["calls"] += 1
             loc_stats["execs"] += len(acts)
-            if not first_call:
-                loc_stats["bytes"] += moved
-                loc_stats["seconds"] += dt
             stats["fused"]["fused_calls"] += 1
             stats["fused"]["fused_execs"] += len(acts)
             # Replay the run's cursor/data effects from the recorded
@@ -407,8 +398,6 @@ class JaxMeshProgram(BackendProgram):
             # locations.
             leader_dev = device_of[seg.leader]
             for op, picks in acts:
-                if recorder is not None:
-                    record_exec_fire(recorder, op, t0, t0 + dt)
                 missing = set(op.outputs) - set(out)
                 if missing:
                     raise RuntimeError(
@@ -454,12 +443,8 @@ class JaxMeshProgram(BackendProgram):
                     if guard is not None
                     else (lambda: fn(inputs))
                 )
-                if recorder is None:
+                with exec_span(recorder, op, leader):
                     out = fire()
-                else:
-                    t0 = time.monotonic()
-                    out = fire()
-                    record_exec_fire(recorder, op, t0, time.monotonic())
                 missing = set(op.outputs) - set(out)
                 if missing:
                     raise RuntimeError(
@@ -476,29 +461,6 @@ class JaxMeshProgram(BackendProgram):
             if not progressed:
                 break
 
-        if fuse:
-            from repro.roofline import device_peaks
-
-            # Host-clock bandwidth of warm fused calls against the HBM
-            # peak of the device that ran them; a device kind without a
-            # published peak gets no entry.
-            roofline = {}
-            for loc, ls in stats["fused"]["locations"].items():
-                peaks = device_peaks(
-                    getattr(device_of[loc], "device_kind", None)
-                )
-                if peaks is None:
-                    continue
-                achieved = (
-                    ls["bytes"] / ls["seconds"] if ls["seconds"] > 0 else 0.0
-                )
-                roofline[loc] = {
-                    "device_kind": device_of[loc].device_kind,
-                    "achieved_bytes_per_s": achieved,
-                    "theoretical_bytes_per_s": peaks.hbm_bytes_per_s,
-                    "fraction_of_roof": achieved / peaks.hbm_bytes_per_s,
-                }
-            stats["fused"]["roofline"] = roofline
         if guard is not None:
             stats["policy"] = guard.counts()
         if not all(c.finished() for c in cursors.values()):

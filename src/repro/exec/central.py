@@ -35,16 +35,18 @@ from typing import Any, Mapping
 
 from repro.core.compile import StepMeta
 from repro.core.parser import dumps
+from repro.obs.events import profiler_span
 
 from .interp import (
     Cursor,
     Deadline,
     call_with_timeout,
     enabled_exec_picks,
+    exec_span,
     first_enabled_comm,
     record_comm_fire,
-    record_exec_fire,
     record_policy_fire,
+    run_span,
 )
 from .policy import FaultPolicy, StepTimeoutError
 from .program import ExecOp, ExecProgram
@@ -62,7 +64,8 @@ class ProgramRuntime:
     Parameters mirror the legacy reduction runtime; ``completed`` names
     steps already finished in a restored snapshot — their recorded outputs
     (harvested from ``initial_payloads``) are replayed instead of
-    re-executing the step body.
+    re-executing the step body.  ``run_id`` is the ``run`` argument of
+    the run's ``swirl.run`` profiler span.
     """
 
     def __init__(
@@ -81,6 +84,7 @@ class ProgramRuntime:
         completed: frozenset[str] = frozenset(),
         recorder=None,
         policy: FaultPolicy | None = None,
+        run_id: int = 0,
     ):
         from repro.workflow.fault import (
             HeartbeatMonitor,
@@ -111,6 +115,7 @@ class ProgramRuntime:
         self.heartbeat = heartbeat or HeartbeatMonitor()
         self.stats = RunStats()
         self.recorder = recorder
+        self.run_id = run_id
         self.completed_execs: set[str] = set(completed)
         self._replayable = frozenset(completed)
         self._lock = threading.Lock()
@@ -235,9 +240,10 @@ class ProgramRuntime:
             )
 
         t0 = time.monotonic()
-        out, speculated = self.speculation.run(
-            with_retry, self.expected_s.get(op.step), pool
-        )
+        with exec_span(self.recorder, op, leader):
+            out, speculated = self.speculation.run(
+                with_retry, self.expected_s.get(op.step), pool
+            )
         dt = time.monotonic() - t0
         if speculated:
             with self._lock:
@@ -249,8 +255,6 @@ class ProgramRuntime:
             )
         with self._lock:
             self.stats.exec_log.append((op.step, leader, dt))
-        if self.recorder is not None:
-            record_exec_fire(self.recorder, op, t0, t0 + dt)
         for l in op.locations:
             self.heartbeat.beat(l)
         return {d: out[d] for d in op.outputs}
@@ -276,6 +280,10 @@ class ProgramRuntime:
 
     # -- main loop --------------------------------------------------------------
     def run(self, *, max_rounds: int = 1_000_000):
+        with run_span("inprocess", self.run_id):
+            return self._run(max_rounds)
+
+    def _run(self, max_rounds: int):
         from repro.workflow.runtime import WorkflowDeadlock
 
         t_start = time.monotonic()
@@ -311,11 +319,12 @@ class ProgramRuntime:
                         continue
                     break  # terminated or deadlocked
 
-                done, _ = wait(
-                    [f for _, _, f in inflight.values()],
-                    timeout=deadline.remaining(),
-                    return_when=FIRST_COMPLETED,
-                )
+                with profiler_span("swirl.wait"):
+                    done, _ = wait(
+                        [f for _, _, f in inflight.values()],
+                        timeout=deadline.remaining(),
+                        return_when=FIRST_COMPLETED,
+                    )
                 for key in [
                     k for k, (_, _, f) in inflight.items() if f in done
                 ]:

@@ -17,9 +17,12 @@ the same :class:`~repro.exec.program.ControlSpec` with real threads.
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from typing import TYPE_CHECKING, Any, Callable, Iterable, Mapping
+
+from repro.obs.events import profiler_span
 
 # .policy is imported lazily inside the helpers below: policy.py pulls in
 # repro.workflow.fault, whose package __init__ imports repro.workflow.threaded,
@@ -42,6 +45,8 @@ __all__ = [
     "Deadline",
     "StepGuard",
     "call_with_timeout",
+    "comm_span",
+    "exec_span",
     "first_enabled_comm",
     "enabled_exec_picks",
     "record_comm_fire",
@@ -49,6 +54,8 @@ __all__ = [
     "record_policy_fire",
     "record_recv_fire",
     "record_send_fire",
+    "run_span",
+    "segment_span",
 ]
 
 
@@ -227,6 +234,12 @@ def enabled_exec_picks(
 # ``recorder is None`` themselves, keeping the untraced hot path free of
 # function calls; these helpers additionally no-op on None so defensive
 # callers pay only the call.
+#
+# The context forms further down (``run_span``, ``exec_span``,
+# ``segment_span``, ``comm_span``) wrap the work itself: they open a span on
+# the profiler's clock (:func:`repro.obs.events.profiler_span`) whether or
+# not a recorder is attached, and on a recorder append the same rows as
+# the plain helpers, stamped around the block.
 # ---------------------------------------------------------------------------
 
 
@@ -287,6 +300,69 @@ def record_policy_fire(recorder, kind: str, location: str, step: str,
         return
     recorder.add(("policy", location, f"{kind}:{step}",
                   t0, t1, None, None, None, None))
+
+
+class _FireSpan:
+    """A profiler span around a block and, where ``record`` is given,
+    ``record(t0, t1)`` with monotonic stamps around it once the block
+    ends without an exception (a failed fire records no row, as before)."""
+
+    __slots__ = ("_span", "_record", "_t0")
+
+    def __init__(self, span, record: Callable[[float, float], None] | None):
+        self._span = span
+        self._record = record
+
+    def __enter__(self) -> "_FireSpan":
+        self._span.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if exc_type is None and self._record is not None:
+            self._record(self._t0, time.monotonic())
+        return self._span.__exit__(exc_type, exc, tb)
+
+
+def run_span(backend: str, run: int):
+    """``swirl.run`` around one whole run of a compiled program; ``run``
+    numbers the program's runs, so every span of one workflow instance
+    shares it."""
+    return profiler_span("swirl.run", backend=backend, run=run)
+
+
+def exec_span(recorder, op: ExecOp, location: str) -> _FireSpan:
+    """``swirl.exec`` around one step fire, with the step and the location
+    whose thread or device runs it; records :func:`record_exec_fire`."""
+    return _FireSpan(
+        profiler_span("swirl.exec", step=op.step, location=location),
+        None if recorder is None
+        else functools.partial(record_exec_fire, recorder, op),
+    )
+
+
+def segment_span(recorder, leader: str, ops: list[ExecOp]) -> _FireSpan:
+    """``swirl.segment`` around one fused call of ``ops`` on ``leader``'s
+    device; records every op's exec rows over the call's interval."""
+
+    def record(t0: float, t1: float) -> None:
+        for op in ops:
+            record_exec_fire(recorder, op, t0, t1)
+
+    return _FireSpan(
+        profiler_span("swirl.segment", leader=leader, execs=len(ops)),
+        None if recorder is None else record,
+    )
+
+
+def comm_span(recorder, op: SendOp, payload: Any) -> _FireSpan:
+    """``swirl.comm`` around one COMM's placement at ``op.dst``; records
+    :func:`record_comm_fire` sized from ``payload``."""
+    return _FireSpan(
+        profiler_span("swirl.comm", data=op.data, src=op.src, dst=op.dst),
+        None if recorder is None
+        else functools.partial(record_comm_fire, recorder, op, nbytes=payload),
+    )
 
 
 # ---------------------------------------------------------------------------

@@ -12,6 +12,13 @@ Cross-pod gradient traffic goes through int8 error-feedback compression
 (:mod:`repro.optim.compress`) — the explicit send/recv structure of the
 SWIRL plan is what makes the compression insertion point well-defined.
 
+The step bodies open profiler spans (:func:`repro.obs.profiler_span`)
+around the host work of an iteration: ``launch.compress`` (one pod's
+eager int8 compression), ``launch.gradsync`` (decompression and the mean
+over pods) and ``launch.sync`` (each read of device values to the host,
+which waits for the device).  They nest in the SWIRL runtime's
+``swirl.exec`` spans on the thread that runs the step.
+
 All "pods" run in this one process and share its default device: the
 host under ``JAX_PLATFORMS=cpu``, or the one chip of a TPU host, where
 every pod's step bodies run on that chip.  The orchestration path (plans,
@@ -43,6 +50,7 @@ from repro.configs import get_config
 from repro.core.translate import TrainPipelineTranslator
 from repro.data import SyntheticLM
 from repro.models import Model, ModelConfig
+from repro.obs import profiler_span
 from repro.optim import AdamWConfig
 from repro.optim import adamw as adamw_mod
 from repro.optim.compress import allreduce_mean, compress, decompress
@@ -79,7 +87,8 @@ def build_step_fns(
             params = inputs[f"params_{i}"]
             grads, metrics = grad_fn(params, inputs[f"batch_{i}"])
             if compress_grads:
-                c, err[i] = compress(grads, err.get(i))
+                with profiler_span("launch.compress", pod=i):
+                    c, err[i] = compress(grads, err.get(i))
                 payload = ("int8", c)
             else:
                 payload = ("raw", grads)
@@ -90,11 +99,13 @@ def build_step_fns(
             opt_state = inputs[f"opt_{i}"]
             mean_grads, metrics = inputs["grad_sync"]
             new_params, new_opt, om = update_fn(mean_grads, opt_state, params)
+            with profiler_span("launch.sync", pod=i):
+                om = {k: float(v) for k, v in om.items()}
             return {
                 f"state_{i}": {
                     "params": new_params,
                     "opt": new_opt,
-                    "metrics": {**metrics, **{k: float(v) for k, v in om.items()}},
+                    "metrics": {**metrics, **om},
                 }
             }
 
@@ -103,17 +114,20 @@ def build_step_fns(
         fns[f"update_{i}"] = update
 
     def gradsync(inputs):
-        parts = []
+        grads = [inputs[f"grad_{i}"] for i in range(n_pods)]
+        with profiler_span("launch.gradsync"):
+            mean = allreduce_mean([
+                decompress(payload) if kind == "int8" else payload
+                for (kind, payload), _ in grads
+            ])
         pod_metrics = []
-        for i in range(n_pods):
-            (kind, payload), metrics = inputs[f"grad_{i}"]
-            parts.append(decompress(payload) if kind == "int8" else payload)
-            pod_metrics.append(metrics)
-        mean = allreduce_mean(parts)
+        for i, (_, m) in enumerate(grads):
+            with profiler_span("launch.sync", pod=i):
+                pod_metrics.append({k: float(v) for k, v in m.items()})
         # Pods hold equal shards, so the mean of their losses is the
         # step's loss over the global batch (per-pod router aux terms).
         metrics = {
-            k: sum(float(m[k]) for m in pod_metrics) / n_pods
+            k: sum(m[k] for m in pod_metrics) / n_pods
             for k in pod_metrics[0]
         }
         return {"grad_sync": (mean, metrics)}
@@ -186,6 +200,7 @@ def train(
     grad_fn = jax.jit(make_grad_step(model))
     update_fn = jax.jit(partial(adamw_mod.update, opt_cfg))
     t0 = time.monotonic()
+    t_first = t0  # end of the first iteration, which pays the compiles
     for it in range(start, start + steps):
         fns, err = build_step_fns(
             grad_fn, update_fn, dataset, n_pods,
@@ -208,14 +223,22 @@ def train(
         params, opt_state = state["params"], state["opt"]
         m = state["metrics"]
         history.append(m)
+        if it == start:
+            t_first = time.monotonic()
         if (it - start) % log_every == 0:
             print(
                 f"step {it:5d} loss={m['loss']:.4f} ce={m['ce']:.4f} "
                 f"gnorm={m.get('grad_norm', 0):.3f}"
             )
-    wall = time.monotonic() - t0
+    t_end = time.monotonic()
+    # The metrics were read to the host, so every iteration has finished.
+    per_step = (
+        f", {(t_end - t_first) / (steps - 1):.2f}s/step after the first"
+        if steps > 1 else ""
+    )
     print(
-        f"[done] {steps} steps in {wall:.1f}s ({wall / steps:.2f}s/step), "
+        f"[done] {steps} steps in {t_end - t0:.1f}s (first "
+        f"{t_first - t0:.1f}s with its compiles{per_step}), "
         f"{retries} step retries"
     )
     return {

@@ -5,6 +5,8 @@ distributed execution trace is a first-class object:
 
 * :class:`TraceRecorder` / :class:`SpanEvent` — low-overhead span capture
   shared by all four backends (``lower(..., trace=True)``);
+* :func:`profiler_span` — a span on the profiler's clock
+  (``jax.profiler.TraceAnnotation``), always on, read from a device trace;
 * :class:`RunProfile` — the structured artifact on every traced result
   (``result.profile``), exportable as Perfetto-loadable Chrome trace JSON;
 * :func:`align` / :class:`ProfileReport` — predicted-vs-actual drift
@@ -18,6 +20,7 @@ from repro.obs.events import (
     TraceRecorder,
     current_trace_id,
     payload_nbytes,
+    profiler_span,
 )
 from repro.obs.export import (
     chrome_trace,
@@ -41,6 +44,7 @@ __all__ = [
     "chrome_trace",
     "current_trace_id",
     "payload_nbytes",
+    "profiler_span",
     "validate_chrome_trace",
     "write_chrome_trace",
 ]

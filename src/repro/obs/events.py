@@ -14,7 +14,12 @@ priority order):
    span for an ``ExecOp``/``SendOp``/``RecvOp`` looks like; the four
    backends merely decide *when* to call them.  Differential tests
    compare :meth:`SpanEvent.identity` multisets across backends.
-3. **Mergeable across processes.**  Multiprocess workers record against
+3. **On the profiler's clock where the work happens.**
+   :func:`profiler_span` opens a ``jax.profiler`` span (XLA's TraceMe) on
+   the thread that does the work, so a device trace names what the host
+   was doing in every gap.  The recording helpers of
+   :mod:`repro.exec.interp` open one around each fire they record.
+4. **Mergeable across processes.**  Multiprocess workers record against
    ``t_zero=0.0`` (absolute worker-monotonic timestamps), ship drained
    batches over the control pipe, and the coordinator :meth:`absorb`\\ s
    them with the clock offset measured on the ready/go handshake.
@@ -22,6 +27,7 @@ priority order):
 
 from __future__ import annotations
 
+import functools
 import sys
 import threading
 import time
@@ -33,6 +39,7 @@ __all__ = [
     "TraceRecorder",
     "current_trace_id",
     "payload_nbytes",
+    "profiler_span",
 ]
 
 #: Per-request trace id, set by the gateway for the duration of a request
@@ -45,6 +52,26 @@ current_trace_id: ContextVar[str | None] = ContextVar(
 #: types; ``phase`` covers compile-pipeline stages (trace/schedule/lower/
 #: compile) recorded by :mod:`repro.api`.
 KINDS = ("exec", "send", "recv", "phase")
+
+
+@functools.cache
+def _trace_annotation() -> type:
+    # Imported on first use: most of the package runs without JAX.
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation
+
+
+def profiler_span(name: str, **meta: Any):
+    """Context manager: one span named ``name`` on the profiler's clock.
+
+    A ``jax.profiler.TraceAnnotation`` (XLA's TraceMe): while a profiler
+    runs, the span lands on the host thread that enters it, nested in
+    that thread's open spans, with ``meta`` as its arguments.  With no
+    profiler running it records nothing and costs about a microsecond.
+    Every span the program puts in the profiler's trace opens here.
+    """
+    return _trace_annotation()(name, **meta)
 
 
 def payload_nbytes(value: Any) -> int:

@@ -1,12 +1,18 @@
-"""``jax`` backend fused segments: when they go eager, when they fail,
-and which peak their roofline is measured against."""
+"""``jax`` backend fused segments: when they go eager and when they fail;
+the bytes its COMMs copy between devices."""
+
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import roofline, swirl
+from repro import swirl
 
 CHAIN = "<l0, {x}, exec(s1, {x} -> {y}, {l0}) . exec(s2, {y} -> {z}, {l0})>"
 
@@ -40,16 +46,40 @@ def test_runtime_error_in_traced_segment_propagates():
         _run({"s1": failing, "s2": lambda i: {"z": i["y"]}}, jnp.ones(4))
 
 
-def test_roofline_only_for_devices_with_published_peaks(monkeypatch):
-    steps = {"s1": lambda i: {"y": i["x"] * 2}, "s2": lambda i: {"z": i["y"] + 1}}
-    exe = swirl.trace(CHAIN).lower("jax", fuse=True).compile(steps)
-    init = {("l0", "x"): jnp.ones(1024)}
-    exe.run(initial_payloads=init)
-    assert exe.run(initial_payloads=init).stats["fused"]["roofline"] == {}
+_COMM_BYTES = """
+import json, jax, jax.numpy as jnp
+from repro import swirl
+plan = swirl.trace(
+    "<a, {x}, exec(s1, {x} -> {y}, {a}) . send(y -> p, a, b)>"
+    " | <b, {}, recv(p, a, b) . exec(s2, {y} -> {z}, {b})>"
+)
+steps = {"s1": lambda i: {"y": i["x"] * 2}, "s2": lambda i: {"z": i["y"] + 1}}
+out = {}
+for n in (1, 2):
+    exe = plan.lower("jax", devices=jax.devices()[:n]).compile(steps)
+    stats = exe.run(initial_payloads={("a", "x"): jnp.ones(256, jnp.float32)}).stats
+    out[n] = {k: stats[k] for k in ("comm_bytes", "bytes_moved", "comms")}
+print(json.dumps(out))
+"""
 
-    kind = jax.devices()[0].device_kind
-    peaks = roofline.DevicePeaks(1e12, 1e9, 1e9, source="test")
-    monkeypatch.setitem(roofline.DEVICE_PEAKS, kind, peaks)
-    rl = exe.run(initial_payloads=init).stats["fused"]["roofline"]["l0"]
-    assert rl["device_kind"] == kind
-    assert rl["theoretical_bytes_per_s"] == 1e9
+
+def test_comm_bytes_count_only_copies_to_another_device():
+    """One COMM of 1 KiB: on one device its ``device_put`` shares the
+    buffer and copies nothing; across two it copies the payload.
+    ``bytes_moved`` counts every placement either way."""
+    root = Path(__file__).resolve().parents[1]
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    env.update(
+        JAX_PLATFORMS="cpu", PYTHONPATH=str(root / "src"),
+        XLA_FLAGS="--xla_force_host_platform_device_count=2",
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", _COMM_BYTES], capture_output=True, text=True,
+        timeout=300, env=env,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["1"]["comms"] == out["2"]["comms"] == 1
+    assert out["1"]["comm_bytes"] == 0
+    assert out["2"]["comm_bytes"] == 1024
+    assert out["1"]["bytes_moved"] == out["2"]["bytes_moved"] > 1024

@@ -448,3 +448,116 @@ class TestPredictedVsActual:
         assert m.exec_s("b") == pytest.approx(0.2)
         with pytest.raises(TypeError):
             CostModel.from_profile(42)
+
+
+# ---------------------------------------------------------------------------
+# Spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def _profiled_spans(tmp_path, fn, prefix):
+    """(name, arguments) of every span named ``prefix*`` in a profiler
+    trace of ``fn()``, in start order."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = [
+        (e.start_ns, e.name, dict(e.stats))
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines
+        for e in line.events
+        if e.name.startswith(prefix)
+    ]
+    return [(name, stats) for _, name, stats in sorted(found, key=lambda x: x[0])]
+
+
+class TestProfilerSpans:
+    def test_fire_span_records_rows_only_when_the_fire_succeeds(self):
+        from repro.exec.interp import exec_span
+        from repro.exec.program import ExecOp
+
+        op = ExecOp(step="s", inputs=(), outputs=("d",), locations=("a", "b"), leader=True)
+        rec = TraceRecorder()
+        with exec_span(rec, op, "a"):
+            pass
+        with pytest.raises(ValueError), exec_span(rec, op, "a"):
+            raise ValueError("step failed")
+        with exec_span(None, op, "a"):  # no recorder: the profiler span only
+            pass
+        spans = rec.drain()
+        assert [(s.kind, s.location, s.name) for s in spans] == [
+            ("exec", "a", "s"), ("exec", "b", "s"),
+        ]
+        assert spans[0].start <= spans[0].end
+
+    def test_trainer_step_bodies_open_launch_spans(self, tmp_path):
+        """Compression per pod, the gradient mean, and every read of device
+        values to the host, with the pod each belongs to."""
+        import jax.numpy as jnp
+
+        from repro.launch.train import build_step_fns
+
+        def grad_fn(params, batch):
+            return {"w": params["w"] * 0.5}, {"loss": jnp.float32(2.0)}
+
+        def update_fn(grads, opt, params):
+            return params, opt, {"lr": jnp.float32(0.1)}
+
+        class Data:
+            def batch(self, step, shard, n_shards):
+                return step
+
+        fns, _ = build_step_fns(grad_fn, update_fn, Data(), 2)
+        params = {"w": jnp.ones(64)}
+
+        def iteration():
+            grads = {
+                f"grad_{i}": fns[f"fwdbwd_{i}"]({f"params_{i}": params, f"batch_{i}": 0})[f"grad_{i}"]
+                for i in range(2)
+            }
+            sync = fns["gradsync"](grads)
+            for i in range(2):
+                fns[f"update_{i}"]({f"params_{i}": params, f"opt_{i}": None, **sync})
+
+        assert _profiled_spans(tmp_path, iteration, "launch.") == [
+            ("launch.compress", {"pod": 0}),
+            ("launch.compress", {"pod": 1}),
+            ("launch.gradsync", {}),
+            ("launch.sync", {"pod": 0}),
+            ("launch.sync", {"pod": 1}),
+            ("launch.sync", {"pod": 0}),
+            ("launch.sync", {"pod": 1}),
+        ]
+
+    def test_fused_segment_span(self, tmp_path):
+        import jax.numpy as jnp
+
+        chain = (
+            "<l0, {x}, exec(s1, {x} -> {y}, {l0}) . exec(s2, {y} -> {z}, {l0})>"
+        )
+        steps = {"s1": lambda i: {"y": i["x"] * 2}, "s2": lambda i: {"z": i["y"] + 1}}
+        exe = swirl.trace(chain).lower("jax", fuse=True, trace=True).compile(steps)
+        init = {("l0", "x"): jnp.ones(16)}
+        exe.run(initial_payloads=init)
+        result = []
+
+        def run():
+            result.append(exe.run(initial_payloads=init))
+
+        assert _profiled_spans(tmp_path, run, "swirl.") == [
+            ("swirl.run", {"backend": "jax", "run": 1}),
+            ("swirl.segment", {"leader": "l0", "execs": 2}),
+        ]
+        # the recorder keeps one exec row per step, over the fused call
+        (profile,) = [r.profile for r in result]
+        execs = [s for s in profile.spans if s.kind == "exec"]
+        assert [s.name for s in execs] == ["s1", "s2"]
+        assert execs[0].start == execs[1].start and execs[0].end == execs[1].end
