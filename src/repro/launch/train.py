@@ -14,10 +14,15 @@ SWIRL plan is what makes the compression insertion point well-defined.
 
 The step bodies open profiler spans (:func:`repro.obs.profiler_span`)
 around the host work of an iteration: ``launch.compress`` (one pod's
-eager int8 compression), ``launch.gradsync`` (decompression and the mean
-over pods) and ``launch.sync`` (each read of device values to the host,
-which waits for the device).  They nest in the SWIRL runtime's
-``swirl.exec`` spans on the thread that runs the step.
+int8 compression, one jitted program), ``launch.gradsync`` (the mean
+over pods of their decompressed payloads, one jitted program) and
+``launch.sync`` (each read of device values to the host, which waits for
+the device).  ``launch.compress`` and ``launch.gradsync`` carry
+``traces``, how many times the jitted bodies behind them had been traced
+when the span opened (:data:`repro.optim.compress.TRACES`): it stays put
+from one iteration to the next once each program is compiled.  The spans
+nest in the SWIRL runtime's ``swirl.exec`` spans on the thread that runs
+the step.
 
 All "pods" run in this one process and share its default device: the
 host under ``JAX_PLATFORMS=cpu``, or the one chip of a TPU host, where
@@ -53,7 +58,7 @@ from repro.models import Model, ModelConfig
 from repro.obs import profiler_span
 from repro.optim import AdamWConfig
 from repro.optim import adamw as adamw_mod
-from repro.optim.compress import allreduce_mean, compress, decompress
+from repro.optim.compress import TRACES, allreduce_mean, compress, decompress_mean
 from repro.workflow import RetryPolicy
 from repro.ckpt import async_save, latest_step, load_checkpoint
 from .cache import configure_compile_cache
@@ -87,12 +92,11 @@ def build_step_fns(
             params = inputs[f"params_{i}"]
             grads, metrics = grad_fn(params, inputs[f"batch_{i}"])
             if compress_grads:
-                with profiler_span("launch.compress", pod=i):
-                    c, err[i] = compress(grads, err.get(i))
-                payload = ("int8", c)
-            else:
-                payload = ("raw", grads)
-            return {f"grad_{i}": (payload, metrics)}
+                with profiler_span(
+                    "launch.compress", pod=i, traces=TRACES["compress"]
+                ):
+                    grads, err[i] = compress(grads, err.get(i))
+            return {f"grad_{i}": (grads, metrics)}
 
         def update(inputs, i=i):
             params = inputs[f"params_{i}"]
@@ -115,11 +119,13 @@ def build_step_fns(
 
     def gradsync(inputs):
         grads = [inputs[f"grad_{i}"] for i in range(n_pods)]
-        with profiler_span("launch.gradsync"):
-            mean = allreduce_mean([
-                decompress(payload) if kind == "int8" else payload
-                for (kind, payload), _ in grads
-            ])
+        payloads = [payload for payload, _ in grads]
+        traces = TRACES["decompress_mean"] + TRACES["allreduce_mean"]
+        with profiler_span("launch.gradsync", traces=traces):
+            if compress_grads:
+                mean = decompress_mean(allreduce_mean, payloads)
+            else:
+                mean = allreduce_mean(payloads)
         pod_metrics = []
         for i, (_, m) in enumerate(grads):
             with profiler_span("launch.sync", pod=i):

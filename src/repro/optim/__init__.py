@@ -7,6 +7,7 @@ from .compress import (
     compress,
     compressed_bytes,
     decompress,
+    decompress_mean,
 )
 from .zero import zero1_specs
 
@@ -19,6 +20,7 @@ __all__ = [
     "global_norm",
     "compress",
     "decompress",
+    "decompress_mean",
     "allreduce_mean",
     "Compressed",
     "compressed_bytes",

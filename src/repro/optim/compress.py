@@ -9,19 +9,34 @@ pod-level gradient before its send/recv exchange:
   residual is added back to the next step's gradient, so the compression
   bias telescopes and SGD-style convergence is preserved.
 
-These are pure functions over pytrees — used by the workflow-level trainer
-(`launch/train.py`) between ``fwdbwd`` and ``update`` steps, and unit-tested
-for the telescoping property.
+``compress``, ``decompress``, ``allreduce_mean`` and ``decompress_mean``
+are jitted programs over pytrees: each call is one dispatch, and XLA
+fuses a leaf's error correction, absmax, rounding and new residual into
+about one pass over it.  They are traced once per tree layout (shapes and
+dtypes) and reused on every later call; :data:`TRACES` counts the traces.
+``compress`` writes the new error over the old one's buffers, and
+``decompress_mean`` never writes the pods' float32 trees out, so a caller
+that runs ahead of the device holds no more of them than it must.  The
+workflow-level trainer (`launch/train.py`) calls them between its
+``fwdbwd`` and ``update`` steps; they are unit-tested against the eager
+formula and for the telescoping property.
 """
 
 from __future__ import annotations
 
-from typing import Any, NamedTuple
+import collections
+from functools import partial
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 PyTree = Any
+
+TRACES: collections.Counter[str] = collections.Counter()
+"""How many times each jitted body here has been traced, by function name.
+A count that stays put over iterations shows every call reused its
+compiled program."""
 
 
 class Compressed(NamedTuple):
@@ -41,13 +56,13 @@ def _dequant_leaf(q: jax.Array, scale: jax.Array) -> jax.Array:
     return q.astype(jnp.float32) * scale
 
 
-def compress(grads: PyTree, error: PyTree | None = None) -> tuple[Compressed, PyTree]:
-    """Quantise ``grads + error``; returns (compressed, new error feedback)."""
-    if error is None:
-        error = jax.tree.map(lambda g: jnp.zeros(g.shape, jnp.float32), grads)
+@partial(jax.jit, donate_argnums=1)
+def _compress(grads: PyTree, error: PyTree) -> tuple[Compressed, PyTree]:
+    TRACES["compress"] += 1
     corrected = jax.tree.map(
         lambda g, e: g.astype(jnp.float32) + e, grads, error
     )
+    # Two maps, as parameter trees hold tuples; CSE merges the duplicate work.
     q = jax.tree.map(lambda c: _quant_leaf(c)[0], corrected)
     s = jax.tree.map(lambda c: _quant_leaf(c)[1], corrected)
     deq = jax.tree.map(_dequant_leaf, q, s)
@@ -55,17 +70,42 @@ def compress(grads: PyTree, error: PyTree | None = None) -> tuple[Compressed, Py
     return Compressed(q=q, scale=s), new_error
 
 
+def compress(grads: PyTree, error: PyTree | None = None) -> tuple[Compressed, PyTree]:
+    """Quantise ``grads + error``; returns (compressed, new error feedback).
+
+    ``error`` is donated: the new error takes its buffers, and it may not
+    be read after the call."""
+    if error is None:
+        error = jax.tree.map(lambda g: jnp.zeros(g.shape, jnp.float32), grads)
+    return _compress(grads, error)
+
+
+@jax.jit
 def decompress(c: Compressed) -> PyTree:
+    TRACES["decompress"] += 1
     return jax.tree.map(_dequant_leaf, c.q, c.scale)
 
 
+@jax.jit
 def allreduce_mean(parts: list[PyTree]) -> PyTree:
-    """Host-side mean of decompressed pod gradients (gradsync step body)."""
+    """Mean of decompressed pod gradients (gradsync step body)."""
+    TRACES["allreduce_mean"] += 1
     n = float(len(parts))
     out = parts[0]
     for p in parts[1:]:
         out = jax.tree.map(lambda a, b: a + b, out, p)
     return jax.tree.map(lambda a: a / n, out)
+
+
+@partial(jax.jit, static_argnums=0)
+def decompress_mean(
+    mean: Callable[[list[PyTree]], PyTree], parts: list[Compressed]
+) -> PyTree:
+    """``mean`` of the decompressed ``parts``, as one program (gradsync
+    step body).  ``mean`` is static: a module-level function such as
+    :func:`allreduce_mean` is traced once per tree layout."""
+    TRACES["decompress_mean"] += 1
+    return mean([decompress(c) for c in parts])
 
 
 def compressed_bytes(c: Compressed) -> int:

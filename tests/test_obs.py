@@ -500,10 +500,12 @@ class TestProfilerSpans:
 
     def test_trainer_step_bodies_open_launch_spans(self, tmp_path):
         """Compression per pod, the gradient mean, and every read of device
-        values to the host, with the pod each belongs to."""
+        values to the host, with the pod each belongs to; once compiled, the
+        compression and the mean carry their programs' trace counts."""
         import jax.numpy as jnp
 
         from repro.launch.train import build_step_fns
+        from repro.optim.compress import TRACES
 
         def grad_fn(params, batch):
             return {"w": params["w"] * 0.5}, {"loss": jnp.float32(2.0)}
@@ -527,10 +529,13 @@ class TestProfilerSpans:
             for i in range(2):
                 fns[f"update_{i}"]({f"params_{i}": params, f"opt_{i}": None, **sync})
 
+        iteration()  # compiles the compression, decompression and mean
+        compress_traces = TRACES["compress"]
+        sync_traces = TRACES["decompress_mean"] + TRACES["allreduce_mean"]
         assert _profiled_spans(tmp_path, iteration, "launch.") == [
-            ("launch.compress", {"pod": 0}),
-            ("launch.compress", {"pod": 1}),
-            ("launch.gradsync", {}),
+            ("launch.compress", {"pod": 0, "traces": compress_traces}),
+            ("launch.compress", {"pod": 1, "traces": compress_traces}),
+            ("launch.gradsync", {"traces": sync_traces}),
             ("launch.sync", {"pod": 0}),
             ("launch.sync", {"pod": 1}),
             ("launch.sync", {"pod": 0}),

@@ -14,6 +14,7 @@ from repro.optim import (
     compress,
     compressed_bytes,
     decompress,
+    decompress_mean,
 )
 from repro.optim import adamw
 from repro.optim.zero import zero1_specs
@@ -124,6 +125,86 @@ class TestCompression:
         b = {"w": jnp.full((4,), 3.0)}
         m = allreduce_mean([a, b])
         assert np.allclose(np.asarray(m["w"]), 2.0)
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+    def test_jitted_compress_matches_eager_formula(self, dtype):
+        """A vector, a matrix and a stacked 3-D leaf (inside a tuple, as
+        parameter trees hold them), with a nonzero error feedback."""
+        ks = jax.random.split(jax.random.key(7), 9)
+        rows = jnp.exp(jax.random.normal(ks[0], (4, 6, 1)))  # per-row ranges
+        grads = {
+            "vec": jax.random.normal(ks[1], (40,)),
+            "mat": jax.random.normal(ks[2], (6, 24)) * rows[0],
+            "blocks": (
+                jax.random.normal(ks[3], (4, 6, 24)) * rows,
+                jax.random.normal(ks[4], (4, 24)) * 1e-3,
+            ),
+        }
+        grads = jax.tree.map(lambda g: g.astype(dtype), grads)
+        error = jax.tree.map(
+            lambda g, k: 0.01 * jax.random.normal(k, g.shape), grads,
+            jax.tree.unflatten(jax.tree.structure(grads), list(ks[5:])),
+        )
+        want = [
+            np.asarray(g, np.float32) + np.asarray(e)
+            for g, e in zip(jax.tree.leaves(grads), jax.tree.leaves(error))
+        ]
+        c, new_error = compress(grads, error)  # error is donated
+        assert all(e.is_deleted() for e in jax.tree.leaves(error))
+
+        for corrected, q, s, ne in zip(
+            want, *(jax.tree.leaves(t) for t in (c.q, c.scale, new_error))
+        ):
+            absmax = np.max(np.abs(corrected), axis=-1, keepdims=True)
+            scale = np.maximum(absmax / np.float32(127.0), np.float32(1e-12))
+            ratio = corrected / scale
+            want_q = np.clip(np.round(ratio), -127, 127).astype(np.int8)
+            want_err = corrected - want_q.astype(np.float32) * scale
+            q = np.asarray(q)
+            assert q.dtype == np.int8 and s.dtype == jnp.float32
+            assert ne.dtype == jnp.float32
+            np.testing.assert_allclose(np.asarray(s), scale, rtol=1e-6)
+            # q may differ only where the ratio sits on a rounding tie
+            off = q != want_q
+            tie = np.abs(np.abs(ratio - np.trunc(ratio)) - 0.5) < 1e-4
+            assert np.all(np.abs(q.astype(int) - want_q)[off] == 1)
+            assert np.all(tie[off])
+            tol = np.broadcast_to(4 * np.finfo(np.float32).eps * absmax, q.shape)
+            np.testing.assert_allclose(
+                np.asarray(ne)[~off], want_err[~off], rtol=0, atol=tol[~off].max()
+            )
+
+        # decompress each part and take the mean, apart and as one program:
+        # the eager sum over parts
+        parts = [c, compress(grads)[0], compress(grads, new_error)[0]]
+        mean = allreduce_mean([decompress(p) for p in parts])
+        fused = decompress_mean(allreduce_mean, parts)
+        for i, (m, f) in enumerate(zip(jax.tree.leaves(mean), jax.tree.leaves(fused))):
+            deq = [
+                np.asarray(jax.tree.leaves(p.q)[i], np.float32)
+                * np.asarray(jax.tree.leaves(p.scale)[i])
+                for p in parts
+            ]
+            # round-off of a sum is relative to the sum of magnitudes
+            bound = 4 * np.finfo(np.float32).eps * sum(np.abs(d) for d in deq) / 3
+            for got in (m, f):
+                err = np.abs(np.asarray(got) - (deq[0] + deq[1] + deq[2]) / np.float32(3))
+                assert np.all(err <= bound), err.max()
+
+    def test_compress_traces_once_per_tree_layout(self):
+        from repro.optim.compress import TRACES
+
+        g = {"w": (jnp.ones((3, 40)), jnp.ones(40, jnp.bfloat16))}
+        before = TRACES["compress"]
+        c, err = compress(g)  # zeros made outside: the same trace as below
+        for _ in range(3):
+            c, err = compress(g, err)
+        assert TRACES["compress"] == before + 1
+        d, m = TRACES["decompress"], TRACES["decompress_mean"]
+        decompress(c), decompress(c)
+        decompress_mean(allreduce_mean, [c, c]), decompress_mean(allreduce_mean, [c, c])
+        assert TRACES["decompress"] == d + 1
+        assert TRACES["decompress_mean"] == m + 1
 
     @settings(max_examples=20, deadline=None)
     @given(st.integers(0, 2**31 - 1))

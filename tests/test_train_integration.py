@@ -77,3 +77,51 @@ def test_compressed_training_tracks_uncompressed():
     la = float(a["history"][-1]["loss"])
     lb = float(b["history"][-1]["loss"])
     assert abs(la - lb) / la < 0.05  # int8+EF stays close
+
+
+def test_gradient_exchange_compiles_once():
+    """Five iterations as ``train()`` runs them (step functions rebuilt,
+    the SWIRL plan compiled and run each time): the compression and the
+    decompress-and-mean are traced in the first iteration and reused in
+    the other four."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro import swirl
+    from repro.core.translate import TrainPipelineTranslator
+    from repro.launch.train import build_step_fns
+    from repro.optim.compress import TRACES
+
+    def grad_fn(params, batch):
+        grads = jax.tree.map(lambda p: (p * batch).astype(jnp.bfloat16), params)
+        return grads, {"loss": jnp.float32(batch)}
+
+    def update_fn(grads, opt, params):
+        new = jax.tree.map(lambda p, g: p - 0.1 * g, params, grads)
+        return new, opt, {"lr": jnp.float32(0.1)}
+
+    class Data:
+        def batch(self, step, shard, n_shards):
+            return jnp.float32(1 + step + shard)
+
+    # shapes no other test uses, so the first iteration traces here
+    params = {"w": jnp.ones((7, 33)), "blocks": (jnp.ones((3, 7, 33)), jnp.ones(33))}
+    lowered = swirl.trace(
+        TrainPipelineTranslator(n_pods=2, with_checkpoint=False)
+    ).optimize(rules=("R1R2", "R3")).lower("inprocess")
+    names = ("compress", "decompress_mean", "allreduce_mean")
+    before = {k: TRACES[k] for k in names}
+    err, counts = {}, []
+    for it in range(5):
+        fns, err = build_step_fns(grad_fn, update_fn, Data(), 2, error_feedback=err)
+        payloads = {}
+        for i in range(2):
+            payloads[(f"pod{i}", f"iter_{i}")] = it
+            payloads[(f"pod{i}", f"params_{i}")] = params
+            payloads[(f"pod{i}", f"opt_{i}")] = None
+        state = lowered.compile(fns).run(initial_payloads=payloads).payload("pod0", "state_0")
+        params = state["params"]
+        counts.append({k: TRACES[k] for k in names})
+    assert all(counts[0][k] > before[k] for k in names), (before, counts[0])
+    assert counts[0] == counts[-1], counts
+    assert sorted(err) == [0, 1]
