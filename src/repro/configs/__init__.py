@@ -11,6 +11,7 @@ from . import (
     internvl2_1b,
     jamba_v0_1_52b,
     llama3_2_3b,
+    mellum2_12b_a2_5b,
     nemotron_4_15b,
     qwen1_5_110b,
     seamless_m4t_medium,
@@ -31,6 +32,7 @@ ARCHS: dict[str, ModelConfig] = {
         granite_moe_1b_a400m,
         deepseek_moe_16b,
         jamba_v0_1_52b,
+        mellum2_12b_a2_5b,
     )
 }
 

@@ -36,6 +36,18 @@ class MoECfg:
     n_shared: int = 0  # always-active shared experts (DeepSeek-MoE)
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01  # load-balance loss weight
+    # Expert parallelism's share, as one chip holds it: the router scores
+    # ``n_routed`` experts (0 -> ``n_experts``), of which this layer holds
+    # the ``n_experts`` from ``first_expert`` on and computes only their
+    # part of the result.  ``dropless`` computes every routed choice that
+    # lands on a held expert as a grouped product, with no capacity.
+    n_routed: int = 0
+    first_expert: int = 0
+    dropless: bool = False
+
+    @property
+    def router_width(self) -> int:
+        return self.n_routed or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -66,6 +78,17 @@ class ModelConfig:
     # Attention details
     use_rope: bool = True  # Jamba: attention without positional encoding
     rope_theta: float = 10_000.0
+    # RoPE of attn_local layers: its own theta (0 -> rope_theta), never YaRN.
+    rope_local_theta: float = 0.0
+    # YaRN on the attn layers (yarn_factor 0 -> plain RoPE): the published
+    # blend of extrapolated and interpolated frequencies between the
+    # correction dimensions of beta_fast/beta_slow at the original length,
+    # with cos and sin scaled by the attention factor.
+    yarn_factor: float = 0.0
+    yarn_original_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.0
     qkv_bias: bool = False
     sliding_window: int = 0  # used by attn_local
     attn_logit_softcap: float = 0.0
@@ -165,9 +188,9 @@ class ModelConfig:
         if ffn == "moe":
             m = self.moe
             mult = 3  # experts are gated MLPs
-            routed = m.n_experts * mult * d * m.d_expert
+            routed = m.n_experts * mult * d * m.d_expert  # held here
             shared = m.n_shared * mult * d * m.d_expert
-            router = d * m.n_experts
+            router = d * m.router_width
             return routed + shared + router
         if ffn == "dense0":  # DeepSeek layer-0 dense MLP (d_ff stored in d_ff)
             return 3 * self.d_model * self.d_ff
@@ -197,14 +220,17 @@ class ModelConfig:
         return n
 
     def active_param_count(self) -> int:
-        """Active params per token (MoE: top_k + shared experts only)."""
+        """Active params per token (MoE: top_k + shared experts only).
+
+        Of the top_k choices over the router's width, a held share of
+        ``n_experts / router_width`` lands here on average."""
         if not any(f == "moe" for _, f in self.layer_seq()):
             return self.param_count()
         d, m = self.d_model, self.moe
-        inactive_experts = m.n_experts - m.top_k
+        inactive_experts = m.n_experts - m.top_k * m.n_experts / m.router_width
         per_moe_layer = inactive_experts * 3 * d * m.d_expert
         n_moe = sum(1 for _, f in self.layer_seq() if f == "moe")
-        return self.param_count() - n_moe * per_moe_layer
+        return round(self.param_count() - n_moe * per_moe_layer)
 
 
 def unrolled_variant(cfg: ModelConfig, *, ssm_chunk: int | None = None) -> ModelConfig:
@@ -223,10 +249,24 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
     prefix = cfg.prefix_pattern
     n_layers = len(prefix) + len(pat)  # one repeat of the pattern
     moe = cfg.moe
-    if moe.n_experts:
+    if moe.n_experts and moe.router_width > moe.n_experts:
+        # A held share of a wider router: 8 routed, the share's fraction
+        # of them held (at least 2), from the same relative offset.
+        held = max(2, 8 * moe.n_experts // moe.router_width)
         moe = dataclasses.replace(
             moe,
-            n_experts=max(4, moe.top_k + 1) if moe.n_experts > 4 else moe.n_experts,
+            n_routed=8,
+            n_experts=held,
+            first_expert=min(8 - held, 8 * moe.first_expert // moe.router_width),
+            top_k=min(moe.top_k, 2),
+            d_expert=32,
+        )
+    elif moe.n_experts:
+        n = max(4, moe.top_k + 1) if moe.n_experts > 4 else moe.n_experts
+        moe = dataclasses.replace(
+            moe,
+            n_experts=n,
+            n_routed=0,
             top_k=min(moe.top_k, 2),
             d_expert=32,
         )

@@ -2,7 +2,8 @@
 
 Attention is implemented "flash-style" in pure JAX: the query axis is
 processed in Python-unrolled chunks so the ``[Lq, Lk]`` score tensor never
-exceeds ``q_chunk × Lk`` — this bounds live memory at 32k prefill and keeps
+exceeds ``q_chunk × Lk`` (in training, ``LIVE_BLOCK`` × the block's live
+keys, causal and windowed) — this bounds live memory at 32k prefill and keeps
 every FLOP visible to ``cost_analysis`` (no while-loops hiding work).  The
 Pallas TPU kernel (:mod:`repro.kernels`) is a drop-in replacement selected
 with ``attn_impl="pallas"`` on real TPUs.
@@ -16,6 +17,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .config import ModelConfig
 
@@ -101,15 +103,67 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     )
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [B, L, H, D]; positions: [B, L] or [L]."""
+def yarn_freqs(
+    head_dim: int, theta: float, *, factor: float, original_len: int,
+    beta_fast: float, beta_slow: float,
+) -> np.ndarray:
+    """YaRN's inverse frequencies [D/2] (float32, as published).
+
+    Dimensions that turn fewer than ``beta_slow`` times over the original
+    length are interpolated (divided by ``factor``), those that turn more
+    than ``beta_fast`` times are kept, and a linear ramp between the two
+    correction dimensions (floored and ceiled) blends them.
+    """
+
+    def correction_dim(rotations: float) -> float:
+        return (head_dim * math.log(original_len / (rotations * 2 * math.pi))) / (
+            2 * math.log(theta)
+        )
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    pos = theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim)
+    ramp = np.clip(
+        (np.arange(head_dim // 2, dtype=np.float32) - low) / (high - low), 0, 1
+    )
+    extrapolate = (1 - ramp).astype(np.float32)
+    inv = (1.0 / (factor * pos)) * (1 - extrapolate) + (1.0 / pos) * extrapolate
+    return inv.astype(np.float32)
+
+
+def layer_rope(cfg: ModelConfig, *, local: bool) -> tuple[Any, float]:
+    """(inverse frequencies, cos/sin scale) of an attention layer's RoPE:
+    ``rope_local_theta`` on windowed layers, YaRN on the others if set."""
+    if local:
+        return rope_freqs(cfg.head_dim, cfg.rope_local_theta or cfg.rope_theta), 1.0
+    if cfg.yarn_factor <= 0.0:
+        return rope_freqs(cfg.head_dim, cfg.rope_theta), 1.0
+    inv = yarn_freqs(
+        cfg.head_dim, cfg.rope_theta, factor=cfg.yarn_factor,
+        original_len=cfg.yarn_original_len, beta_fast=cfg.yarn_beta_fast,
+        beta_slow=cfg.yarn_beta_slow,
+    )
+    return jnp.asarray(inv), cfg.yarn_attention_factor
+
+
+def apply_rope(
+    x: jax.Array, positions: jax.Array, theta: float = 10_000.0, *,
+    freqs: jax.Array | None = None, scale: float = 1.0,
+) -> jax.Array:
+    """x: [B, L, H, D]; positions: [B, L] or [L].  ``freqs`` replaces the
+    plain frequencies of ``theta``; ``scale`` multiplies cos and sin."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)  # [D/2]
+    if freqs is None:
+        freqs = rope_freqs(d, theta)  # [D/2]
     if positions.ndim == 1:
         positions = positions[None, :]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, L, D/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -200,6 +254,65 @@ def _sdpa_chunk(
     return out.astype(v.dtype)
 
 
+# Queries per block of the training path (sequences longer than sdpa's
+# ``q_chunk``): each block reads only its live keys.
+LIVE_BLOCK = 512
+# Keys a training chunk's softmax reduces over in one piece.  XLA:TPU
+# lowers a longer reduction (scores laid out queries-minor) to fusions
+# whose cost grows with the square of the keys.
+KEY_BLOCK = 2048
+
+
+def _live_chunk(
+    q: jax.Array,  # [B, c, Hkv, G, D] queries in the model's dtype
+    k: jax.Array,  # [B, n, Hkv, D] the chunk's live keys
+    v: jax.Array,  # [B, n, Hkv, D]
+    q_pos: jax.Array,  # [c]
+    k_pos: jax.Array,  # [n]
+    *,
+    causal: bool,
+    window: int,
+    softcap: float,
+) -> jax.Array:
+    """One training chunk over its live keys; returns [B, c, Hkv, G, D].
+
+    Scores, ``[B, Hkv, G, c, keys]``, are the product of the unscaled
+    operands in float32, then scaled.  The softmax is exact and split over
+    blocks of at most ``KEY_BLOCK`` keys: their maxima, then the shared
+    maximum, the exponentials' sums and the value products per block.
+    """
+    scale = 1.0 / math.sqrt(q.shape[-1])
+    blocks = []
+    for lo in range(0, k.shape[1], KEY_BLOCK):
+        kp = k_pos[lo : lo + KEY_BLOCK]
+        s = jnp.einsum(
+            "bchgd,bkhd->bhgck", q, k[:, lo : lo + KEY_BLOCK],
+            preferred_element_type=jnp.float32,
+        ) * scale
+        if softcap > 0.0:
+            s = jnp.tanh(s / softcap) * softcap
+        mask = jnp.ones((q_pos.shape[0], kp.shape[0]), bool)
+        if causal:
+            mask &= q_pos[:, None] >= kp[None, :]
+        if window > 0:
+            mask &= q_pos[:, None] - kp[None, :] < window
+        blocks.append(jnp.where(mask, s, -1e30))
+    m = blocks[0].max(axis=-1, keepdims=True)
+    for s in blocks[1:]:
+        m = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+    m = jax.lax.stop_gradient(m)
+    total, out = 0.0, 0.0
+    for i, s in enumerate(blocks):
+        e = jnp.exp(s - m)
+        total = total + e.sum(axis=-1, keepdims=True)
+        out = out + jnp.einsum(
+            "bhgck,bkhd->bhgcd", e.astype(v.dtype), v[:, i * KEY_BLOCK : (i + 1) * KEY_BLOCK],
+            preferred_element_type=jnp.float32,
+        )
+    out = out / total  # [B, Hkv, G, c, D]
+    return jnp.moveaxis(out, 3, 1).astype(v.dtype)
+
+
 def sdpa(
     q: jax.Array,  # [B, Lq, Hq, D]
     k: jax.Array,  # [B, Lk, Hkv, D]
@@ -214,6 +327,15 @@ def sdpa(
     stride_chunks: bool = False,
 ) -> jax.Array:
     """Chunked-query GQA attention; returns [B, Lq, Hq, D].
+
+    Self-attention over a sequence longer than ``q_chunk`` (training: no
+    cache, no query offset) reads, for each block of ``LIVE_BLOCK``
+    queries (``q_chunk`` if fewer), only its live keys:
+    ``[start - window + 1, stop)`` windowed (the start floored to a
+    block), ``[0, stop)`` causal.  The masked softmax over that range is
+    the masked softmax over all keys, minus the dead keys' zeros.  Each
+    chunk is rematerialised on its own, so the backward pass holds one
+    chunk's scores at a time and memory stays linear in the length.
 
     ``stride_chunks``: chunk the query axis by STRIDE instead of contiguous
     ranges — used when Lq is sequence-sharded over the TP axis, so every
@@ -243,6 +365,25 @@ def sdpa(
     if lq <= q_chunk:
         qp = offs + jnp.arange(lq, dtype=jnp.int32)
         return chunk_out(qs, qp, lq)
+    live = (
+        isinstance(q_offset, int) and q_offset == 0 and kv_valid is None
+        and k.shape[1] == lq and not stride_chunks
+    )
+    if live:
+        size = min(LIVE_BLOCK, q_chunk)
+        assert lq % size == 0, (lq, size)
+        block = jax.checkpoint(
+            partial(_live_chunk, causal=causal, window=window, softcap=softcap)
+        )
+        qb = q.reshape(b, lq, hkv, g, d)
+        outs = []
+        for start in range(0, lq, size):
+            stop = start + size
+            hi = stop if causal else lq
+            lo = max(0, start - window + 1) // size * size if window > 0 else 0
+            o = block(qb[:, start:stop], k[:, lo:hi], v[:, lo:hi], k_pos[start:stop], k_pos[lo:hi])
+            outs.append(o.reshape(b, size, hq, d))
+        return jnp.concatenate(outs, axis=1)
     assert lq % q_chunk == 0, (lq, q_chunk)
     n = lq // q_chunk
     if stride_chunks:
@@ -287,8 +428,17 @@ def attention_block(
     k = linear(p["k"], x).reshape(b, l, cfg.n_kv_heads, cfg.head_dim)
     v = linear(p["v"], x).reshape(b, l, cfg.n_kv_heads, cfg.head_dim)
     if use_rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        freqs, scale = layer_rope(cfg, local=window > 0)
+        q = apply_rope(q, positions, freqs=freqs, scale=scale)
+        k = apply_rope(k, positions, freqs=freqs, scale=scale)
+
+    def attend(q, k, v, **kw):
+        # Named for the device trace: windowed and full layers apart.
+        with jax.named_scope("attn.window" if window > 0 else "attn.full"):
+            return sdpa(
+                q, k, v, causal=causal, window=window,
+                softcap=cfg.attn_logit_softcap, **kw,
+            )
 
     if cache is None:
         # H1 (hints): explicit attention sharding.  GSPMD's default for GQA
@@ -318,10 +468,7 @@ def attention_block(
             q = constrain(q, dp, None, h.tp_axis, None)
             k = constrain(k, dp, None, h.tp_axis, None)
             v = constrain(v, dp, None, h.tp_axis, None)
-            out = sdpa(
-                q, k, v, causal=causal, window=window,
-                softcap=cfg.attn_logit_softcap,
-            )
+            out = attend(q, k, v)
             out = constrain(out, dp, None, h.tp_axis, None)
         elif seq_parallel:
             dp = h.dp_spec()
@@ -331,18 +478,12 @@ def attention_block(
             # ≤4k: the TP split already bounds per-device score memory —
             # skip chunking (strided chunks lower to gather/scatter whose
             # backward re-introduces full-residual collectives, §Perf it.3).
-            out = sdpa(
-                q, k, v, causal=causal, window=window,
-                softcap=cfg.attn_logit_softcap,
-                q_chunk=l if l <= 4096 else 2048,
-                stride_chunks=True,
+            out = attend(
+                q, k, v, q_chunk=l if l <= 4096 else 2048, stride_chunks=True,
             )
             out = constrain(out, dp, h.tp_axis, None, None)
         else:
-            out = sdpa(
-                q, k, v, causal=causal, window=window,
-                softcap=cfg.attn_logit_softcap,
-            )
+            out = attend(q, k, v)
         new_cache = None
     else:
         # Write new K/V rows at cache["len"], then attend over valid rows.
@@ -350,11 +491,7 @@ def attention_block(
         ck = jax.lax.dynamic_update_slice(cache["k"], k, (0, idx, 0, 0))
         cv = jax.lax.dynamic_update_slice(cache["v"], v, (0, idx, 0, 0))
         valid = idx + l
-        out = sdpa(
-            q, ck, cv, causal=causal, window=window,
-            softcap=cfg.attn_logit_softcap,
-            q_offset=idx, kv_valid=valid,
-        )
+        out = attend(q, ck, cv, q_offset=idx, kv_valid=valid)
         new_cache = {"k": ck, "v": cv, "len": valid}
     return linear(p["o"], out.reshape(b, l, cfg.q_dim)), new_cache
 

@@ -35,7 +35,7 @@ from .layers import (
     linear,
     init_linear,
 )
-from .moe import apply_moe, init_moe
+from .moe import apply_moe, apply_moe_held, init_moe
 from .recurrent import (
     MambaState,
     MLSTMState,
@@ -132,10 +132,11 @@ def apply_block(
     cache: Params | None,  # per-layer entry (no "len")
     cache_len: jax.Array | None,  # shared scalar, None when training
     enc_out: jax.Array | None = None,
-) -> tuple[jax.Array, Params | None, jax.Array]:
-    """Returns (x', new cache entry, aux loss)."""
+) -> tuple[jax.Array, Params | None, dict[str, jax.Array]]:
+    """Returns (x', new cache entry, stats): ``stats["aux"]`` is the aux
+    loss, beside the held-experts layer's counters."""
     mixer, ffn = kind
-    aux = jnp.zeros((), jnp.float32)
+    stats = {"aux": jnp.zeros((), jnp.float32)}
     h = apply_norm(cfg, p["norm1"], x)
 
     new_cache: Params | None = None
@@ -185,16 +186,19 @@ def apply_block(
             from .moe import apply_moe_sharded
 
             hints = get_hints()
-            if (
+            if cfg.moe.dropless:
+                out, aux, counters = apply_moe_held(cfg, p["ffn"], h)
+                stats = {"aux": aux, **counters}
+            elif (
                 hints is not None
                 and hints.local_moe_dispatch
                 and cfg.moe.n_experts % hints.tp_size == 0
                 and (x.shape[0] * x.shape[1]) % hints.dp_size == 0
                 and x.shape[0] % hints.dp_size == 0
             ):
-                out, aux = apply_moe_sharded(cfg, p["ffn"], h)
+                out, stats["aux"] = apply_moe_sharded(cfg, p["ffn"], h)
             else:
-                out, aux = apply_moe(cfg, p["ffn"], h)
+                out, stats["aux"] = apply_moe(cfg, p["ffn"], h)
         else:
             out = apply_mlp(cfg, p["ffn"], h)
         if cfg.post_block_norm:
@@ -202,7 +206,16 @@ def apply_block(
         x = x + out
     if cache is not None and new_cache is None:
         new_cache = cache
-    return x, new_cache, aux
+    return x, new_cache, stats
+
+
+def _merge_stats(acc: dict, stats: dict) -> dict:
+    """Sum the aux loss and held shares over layers; the busiest layer's
+    load is the stack's."""
+    out = dict(acc)
+    for k, v in stats.items():
+        out[k] = jnp.maximum(acc[k], v) if k == "moe_load_max" else acc[k] + v
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -258,7 +271,10 @@ def _apply_stack(
     cache: Params | None,
     cache_len: jax.Array | None,
     enc_out: jax.Array | None = None,
-) -> tuple[jax.Array, Params | None, jax.Array]:
+) -> tuple[jax.Array, Params | None, dict[str, jax.Array]]:
+    """Returns (x, new cache, stats): the aux loss summed over layers and,
+    for held experts, the counters of :func:`~.moe.apply_moe_held` (share
+    averaged over the MoE layers, load the busiest layer's)."""
     # H4 (hints): Megatron-SP residual stream — between blocks the
     # [B, L, d] activations live sequence-sharded over the TP axis, so the
     # backward activation-grad exchange lowers to reduce-scatter/all-gather
@@ -279,7 +295,12 @@ def _apply_stack(
         return constrain(z, h.dp_spec(), h.tp_axis, None) if sp_resid else z
 
     x = sp(x)
-    aux_total = jnp.zeros((), jnp.float32)
+    n_moe = sum(f == "moe" for _, f in cfg.layer_seq())
+    keys = ("aux",)
+    if cfg.moe.dropless and n_moe:
+        keys += ("moe_held_share", "moe_load_max")
+    stats = {k: jnp.zeros((), jnp.float32) for k in keys}
+    policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
     new_prefix = []
     for i, kind in enumerate(cfg.prefix_pattern):
         c = cache["prefix"][i] if cache is not None else None
@@ -288,41 +309,44 @@ def _apply_stack(
             positions=positions, causal=causal, cache_len=cache_len,
         )
         if cfg.remat:
-            block_fn = jax.checkpoint(
-                block_fn,
-                policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-            )
-        x, nc, aux = block_fn(params["prefix"][i], x, cache=c, enc_out=enc_out)
+            block_fn = jax.checkpoint(block_fn, policy=policy)
+        x, nc, st = block_fn(params["prefix"][i], x, cache=c, enc_out=enc_out)
         x = sp(x)
         new_prefix.append(nc)
-        aux_total = aux_total + aux
+        stats = _merge_stats(stats, st)
+
+    # Remat is per layer: a one-layer period checkpoints the scan body, a
+    # longer one each of its layers, so that the backward pass holds one
+    # layer's intermediates at a time.
+    per_layer_remat = cfg.remat and len(cfg.pattern) > 1
 
     def body_fn(carry, per_layer):
-        x, aux_acc = carry
+        x, acc = carry
         p_j, c_j = per_layer
         new_c = []
         for j, kind in enumerate(cfg.pattern):
-            x, nc, aux = apply_block(
-                cfg, kind, p_j[j], x,
-                positions=positions, causal=causal,
-                cache=c_j[j] if c_j is not None else None,
-                cache_len=cache_len, enc_out=enc_out,
+            block_fn = partial(
+                apply_block, cfg, kind,
+                positions=positions, causal=causal, cache_len=cache_len,
+            )
+            if per_layer_remat:
+                block_fn = jax.checkpoint(block_fn, policy=policy)
+            x, nc, st = block_fn(
+                p_j[j], x, cache=c_j[j] if c_j is not None else None,
+                enc_out=enc_out,
             )
             x = sp(x)
             new_c.append(nc)
-            aux_acc = aux_acc + aux
-        return (x, aux_acc), tuple(new_c) if c_j is not None else None
+            acc = _merge_stats(acc, st)
+        return (x, acc), tuple(new_c) if c_j is not None else None
 
     if cfg.repeats > 0:
         body_cache = cache["body"] if cache is not None else None
         fn = body_fn
-        if cfg.remat:
-            fn = jax.checkpoint(
-                body_fn,
-                policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-            )
-        (x, aux_total), new_body = jax.lax.scan(
-            fn, (x, aux_total), (params["body"], body_cache)
+        if cfg.remat and not per_layer_remat:
+            fn = jax.checkpoint(body_fn, policy=policy)
+        (x, stats), new_body = jax.lax.scan(
+            fn, (x, stats), (params["body"], body_cache)
         )
     else:
         new_body = cache["body"] if cache is not None else None
@@ -330,7 +354,9 @@ def _apply_stack(
     new_cache = None
     if cache is not None:
         new_cache = {"prefix": new_prefix, "body": new_body}
-    return x, new_cache, aux_total
+    if "moe_held_share" in stats:
+        stats["moe_held_share"] = stats["moe_held_share"] / n_moe
+    return x, new_cache, stats
 
 
 # ---------------------------------------------------------------------------
@@ -421,6 +447,20 @@ class Model:
         patch_embeds: jax.Array | None = None,  # vision frontend (prepended)
     ) -> tuple[jax.Array, jax.Array]:
         """Returns (logits [B, L(+P), Vp], aux_loss)."""
+        logits, stats = self._forward(
+            params, tokens, src_embeds=src_embeds, patch_embeds=patch_embeds
+        )
+        return logits, stats["aux"]
+
+    def _forward(
+        self,
+        params: Params,
+        tokens: jax.Array,
+        *,
+        src_embeds: jax.Array | None,
+        patch_embeds: jax.Array | None,
+    ) -> tuple[jax.Array, dict[str, jax.Array]]:
+        """Returns (logits, the stack's stats: aux loss and MoE counters)."""
         cfg = self.cfg
         x = self._embed(params, tokens)
         enc_out = None
@@ -432,12 +472,12 @@ class Model:
             pe = linear(params["vision_proj"], patch_embeds.astype(x.dtype))
             x = jnp.concatenate([pe, x], axis=1)
         pos = jnp.arange(x.shape[1], dtype=jnp.int32)
-        x, _, aux = _apply_stack(
+        x, _, stats = _apply_stack(
             cfg, params["decoder"], x,
             positions=pos, causal=True, cache=None, cache_len=None,
             enc_out=enc_out,
         )
-        return self._logits(params, x), aux
+        return self._logits(params, x), stats
 
     # -- loss ----------------------------------------------------------------
     def loss(
@@ -447,9 +487,10 @@ class Model:
         *,
         seq_chunk: int = 1024,
     ) -> tuple[jax.Array, dict[str, jax.Array]]:
-        """Next-token CE (labels == -1 ignored) + MoE aux loss."""
+        """Next-token CE (labels == -1 ignored) + MoE aux loss; a held
+        experts' layer adds its counters to the metrics."""
         cfg = self.cfg
-        logits, aux = self.forward(
+        logits, stats = self._forward(
             params,
             batch["tokens"],
             src_embeds=batch.get("src_embeds"),
@@ -472,8 +513,9 @@ class Model:
             total = total - jnp.sum(tgt * mask)
             count = count + jnp.sum(mask)
         ce = total / jnp.maximum(count, 1.0)
+        aux = stats.pop("aux")
         loss = ce + cfg.moe.router_aux_weight * aux
-        return loss, {"ce": ce, "aux": aux, "tokens": count}
+        return loss, {"ce": ce, "aux": aux, "tokens": count, **stats}
 
     # -- serving ----------------------------------------------------------------
     def init_cache(self, batch: int, max_len: int) -> Params:

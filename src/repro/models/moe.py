@@ -13,6 +13,11 @@ Dispatch is scatter-based (TPU-friendly, EP-shardable):
 
 The auxiliary load-balance loss (Switch §2.2 form) is returned so the train
 step can add ``router_aux_weight ×`` it.
+
+:func:`apply_moe_held` is the dropless layer of expert parallelism: the
+router scores all ``router_width`` experts, and the layer computes the part
+of the result its held experts give, every choice that lands on them, as
+grouped products over the choices sorted by expert.
 """
 
 from __future__ import annotations
@@ -35,7 +40,7 @@ def init_moe(key, cfg: ModelConfig, dtype) -> Params:
     ks = jax.random.split(key, 5)
     scale = 1.0 / math.sqrt(d)
     p: Params = {
-        "router": init_linear(ks[0], d, m.n_experts, jnp.float32),
+        "router": init_linear(ks[0], d, m.router_width, jnp.float32),
         "gate": (jax.random.normal(ks[1], (m.n_experts, d, m.d_expert)) * scale).astype(dtype),
         "up": (jax.random.normal(ks[2], (m.n_experts, d, m.d_expert)) * scale).astype(dtype),
         "down": (
@@ -117,6 +122,66 @@ def apply_moe(
             jax.nn.silu(linear(sh["gate"], xf)) * linear(sh["up"], xf),
         )
     return y.reshape(b, l, d), aux
+
+
+def apply_moe_held(
+    cfg: ModelConfig, p: Params, x: jax.Array
+) -> tuple[jax.Array, jax.Array, dict[str, jax.Array]]:
+    """x: [B, L, d] → (y, aux_loss, counters): the held experts' share.
+
+    Routes over ``router_width`` experts (top-k, renormalised weights, the
+    aux loss over all of them), keeps the choices that land on experts
+    ``[first_expert, first_expert + n_experts)``, and computes them with
+    no capacity: sorted by expert, one ``ragged_dot`` per projection, the
+    weighted rows added back to their tokens.  What the other experts
+    would add is left out: that is another chip's share.  The counters are
+    ``moe_held_share`` (held choices ÷ all choices) and ``moe_load_max``
+    (the busiest held expert's choices ÷ the held mean).
+    """
+    m = cfg.moe
+    b, l, d = x.shape
+    t, k, held = b * l, m.top_k, m.n_experts
+    xf = x.reshape(t, d)
+    with jax.named_scope("moe.route"):
+        logits = linear(p["router"], xf.astype(jnp.float32))  # [T, E_routed]
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, experts = jax.lax.top_k(probs, k)
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        density = jnp.mean(
+            jnp.sum(jax.nn.one_hot(experts, m.router_width), axis=1), axis=0
+        )
+        aux = m.router_width * jnp.sum(density / k * jnp.mean(probs, axis=0))
+
+        # Held choices first, sorted by expert; the others (key ``held``)
+        # follow and are computed by no group.
+        local = experts.reshape(t * k) - m.first_expert
+        mine = (local >= 0) & (local < held)
+        key = jnp.where(mine, local, held)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+        token = order // k
+        valid = mine[order]
+        # Rows past the groups are no product's, and a grouped product
+        # leaves them undefined: select (never multiply) them away, here
+        # and in the combine, so that neither pass reads them.
+        rows = jnp.where(valid[:, None], xf[token], 0)
+    with jax.named_scope("moe.held"):
+        h = jax.nn.silu(jax.lax.ragged_dot(rows, p["gate"], sizes)) * (
+            jax.lax.ragged_dot(rows, p["up"], sizes)
+        )
+        out = jax.lax.ragged_dot(h, p["down"], sizes)  # [T·k, d]
+        out = jnp.where(valid[:, None], out, 0)
+    with jax.named_scope("moe.route"):
+        w = weights.reshape(t * k)[order]
+        y = jnp.zeros((t, d), jnp.float32).at[token].add(
+            out.astype(jnp.float32) * w[:, None]
+        )
+        n_held = jnp.sum(sizes).astype(jnp.float32)
+        counters = {
+            "moe_held_share": n_held / (t * k),
+            "moe_load_max": jnp.max(sizes) * held / jnp.maximum(n_held, 1.0),
+        }
+    return y.astype(x.dtype).reshape(b, l, d), aux, counters
 
 
 # ---------------------------------------------------------------------------

@@ -88,6 +88,7 @@ def test_full_config_exactness(arch):
         "granite-moe-1b-a400m": (24, 1024, 16, 8, 512, 49155),
         "deepseek-moe-16b": (28, 2048, 16, 16, 10944, 102400),
         "jamba-v0.1-52b": (32, 4096, 32, 8, 14336, 65536),
+        "mellum2-12b-a2.5b": (28, 2304, 32, 4, 7168, 98304),
     }[arch]
     assert cfg.n_layers == assignment[0]
     assert cfg.d_model == assignment[1]
