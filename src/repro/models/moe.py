@@ -17,12 +17,16 @@ step can add ``router_aux_weight ×`` it.
 :func:`apply_moe_held` is the dropless layer of expert parallelism: the
 router scores all ``router_width`` experts, and the layer computes the part
 of the result its held experts give, every choice that lands on them, as
-grouped products over the choices sorted by expert.
+grouped products over the choices sorted by expert.  Its dispatch and its
+combine are gathers by the sort's permutation and by its inverse, and so
+are their transposes (:func:`_take_rows`): no row-wide scatter-add in either
+pass.
 """
 
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Any
 
 import jax
@@ -124,6 +128,32 @@ def apply_moe(
     return y.reshape(b, l, d), aux
 
 
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _take_rows(f: int, x: jax.Array, src: jax.Array, inv: jax.Array) -> jax.Array:
+    """``x[src // f]``: row ``i`` of the result is row ``src[i] // f`` of
+    ``x``, where ``src`` permutes ``range(f * len(x))``, the ``f`` copies of
+    each row, and ``inv`` is its inverse permutation.
+
+    The transpose is a gather too, not a scatter-add: row ``r``'s cotangent
+    is the float32 sum of its copies' cotangents, which lie at
+    ``inv[r*f : (r+1)*f]``.
+    """
+    return x[src // f]
+
+
+def _take_rows_fwd(f, x, src, inv):
+    return _take_rows(f, x, src, inv), inv
+
+
+def _take_rows_bwd(f, inv, ct):
+    with jax.named_scope("moe.route"):
+        copies = ct[inv].reshape(-1, f, ct.shape[-1])
+        return jnp.sum(copies, axis=1, dtype=jnp.float32).astype(ct.dtype), None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
 def apply_moe_held(
     cfg: ModelConfig, p: Params, x: jax.Array
 ) -> tuple[jax.Array, jax.Array, dict[str, jax.Array]]:
@@ -132,8 +162,11 @@ def apply_moe_held(
     Routes over ``router_width`` experts (top-k, renormalised weights, the
     aux loss over all of them), keeps the choices that land on experts
     ``[first_expert, first_expert + n_experts)``, and computes them with
-    no capacity: sorted by expert, one ``ragged_dot`` per projection, the
-    weighted rows added back to their tokens.  What the other experts
+    no capacity: sorted by expert, one ``ragged_dot`` per projection.  The
+    rows are dispatched by a gather through the sort's permutation, and
+    each token's result is the float32 weighted sum of its ``top_k``
+    choices' rows, gathered through the inverse permutation; both gathers
+    transpose to gathers by the other permutation.  What the other experts
     would add is left out: that is another chip's share.  The counters are
     ``moe_held_share`` (held choices ÷ all choices) and ``moe_load_max``
     (the busiest held expert's choices ÷ the held mean).
@@ -158,13 +191,13 @@ def apply_moe_held(
         mine = (local >= 0) & (local < held)
         key = jnp.where(mine, local, held)
         order = jnp.argsort(key, stable=True)
+        inv = jnp.argsort(order)
         sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
-        token = order // k
         valid = mine[order]
         # Rows past the groups are no product's, and a grouped product
         # leaves them undefined: select (never multiply) them away, here
         # and in the combine, so that neither pass reads them.
-        rows = jnp.where(valid[:, None], xf[token], 0)
+        rows = jnp.where(valid[:, None], _take_rows(k, xf, order, inv), 0)
     with jax.named_scope("moe.held"):
         h = jax.nn.silu(jax.lax.ragged_dot(rows, p["gate"], sizes)) * (
             jax.lax.ragged_dot(rows, p["up"], sizes)
@@ -172,10 +205,8 @@ def apply_moe_held(
         out = jax.lax.ragged_dot(h, p["down"], sizes)  # [T·k, d]
         out = jnp.where(valid[:, None], out, 0)
     with jax.named_scope("moe.route"):
-        w = weights.reshape(t * k)[order]
-        y = jnp.zeros((t, d), jnp.float32).at[token].add(
-            out.astype(jnp.float32) * w[:, None]
-        )
+        choice_out = _take_rows(1, out, inv, order).reshape(t, k, d)
+        y = jnp.sum(choice_out.astype(jnp.float32) * weights[..., None], axis=1)
         n_held = jnp.sum(sizes).astype(jnp.float32)
         counters = {
             "moe_held_share": n_held / (t * k),

@@ -15,6 +15,7 @@ from __future__ import annotations
 import dataclasses
 import importlib.util
 import math
+import re
 import sys
 from functools import partial
 from pathlib import Path
@@ -29,7 +30,8 @@ from repro.models import Model, ModelConfig, MoECfg, smoke_variant
 from repro.models import layers
 from repro.models.moe import apply_moe_held, init_moe
 
-REF = Path(__file__).resolve().parents[1] / "benchmarks/chip/configs/mellum2-12b-a2.5b.4l.py"
+BENCH = Path(__file__).resolve().parents[1] / "benchmarks/chip"
+REF = BENCH / "configs/mellum2-12b-a2.5b.4l.py"
 SEED = 2**33 + 7
 # float32 on both sides: the logits agree to 6.3e-7 of their largest, the
 # loss to 8e-8, the gradients to 1.5e-6 (worst leaf); the limits leave
@@ -98,8 +100,8 @@ def reference_outputs(ref, m, params, b):
 
 def program_outputs(model, m, params, b):
     logits, _ = jax.jit(model.forward)(params, b["tokens"])
-    (loss, _), grads = jax.jit(jax.value_and_grad(model.loss, has_aux=True))(params, b)
-    return logits[..., : m["vocab"]], loss, grads
+    (loss, metrics), grads = jax.jit(jax.value_and_grad(model.loss, has_aux=True))(params, b)
+    return logits[..., : m["vocab"]], loss, grads, metrics
 
 
 def rel(a, b) -> float:
@@ -107,25 +109,63 @@ def rel(a, b) -> float:
     return float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-12))
 
 
-@pytest.fixture(scope="module")
-def case(ref):
+# Where the routed choices land besides the seeded mix of the unparametrised
+# tests: every token's top-k on the held experts, the same k for every token
+# (a zero router ties all 8, so each token takes experts 0..3, held here:
+# the collapse the chip's runs reach within five steps); and on none of
+# them.  Each case gives its MoE fields, whether the router is zeroed, and
+# the held share it must read.
+EDGE_ROUTING = [
+    pytest.param({"n_experts": 4, "first_expert": 0}, True, 1.0, id="all_on_held"),
+    pytest.param({"first_expert": 8}, False, 0.0, id="none_held"),
+]
+
+
+def zero_router(params: dict) -> dict:
+    """The reference's parameter tree with every layer's router zeroed."""
+    body = tuple(
+        dict(blk, ffn=dict(blk["ffn"], router={"w": jnp.zeros_like(blk["ffn"]["router"]["w"])}))
+        for blk in params["decoder"]["body"]
+    )
+    return dict(params, decoder=dict(params["decoder"], body=body))
+
+
+def make_case(ref, m: dict, zeroed: bool = False):
     """Seeded weights, a batch, and the reference's logits, loss and
     gradients on them."""
-    m = small()
     params = jax.jit(lambda k: ref.init_params(m, k))(ref.seed_key_data(SEED))
+    if zeroed:
+        params = zero_router(params)
     b = batch(m)
     return params, b, reference_outputs(ref, m, params, b)
 
 
-def test_logits_loss_and_gradients_match_reference(case, blocks_of_16):
-    m = small()
-    params, b, want = case
+@pytest.fixture(scope="module")
+def case(ref):
+    return make_case(ref, small())
+
+
+def assert_matches_reference(m, params, b, want):
+    """The program's logits, loss and gradients against the reference's;
+    returns the program's metrics."""
     got = program_outputs(Model(program_config(m)), m, params, b)
     assert rel(got[0], want[0]) < LOGIT_TOL
     assert rel(got[1], want[1]) < LOGIT_TOL
     flat_want = jax.tree_util.tree_flatten_with_path(want[2])[0]
     for (path, g), w in zip(flat_want, jax.tree.leaves(got[2])):
         assert rel(w, g) < GRAD_TOL, jax.tree_util.keystr(path)
+    return got[3]
+
+
+def test_logits_loss_and_gradients_match_reference(case, blocks_of_16):
+    assert_matches_reference(small(), *case)
+
+
+@pytest.mark.parametrize("moe_fields, zeroed, share", EDGE_ROUTING)
+def test_edge_routing_matches_reference(ref, moe_fields, zeroed, share, blocks_of_16):
+    m = small(**moe_fields)
+    metrics = assert_matches_reference(m, *make_case(ref, m, zeroed))
+    assert float(metrics["moe_held_share"]) == share
 
 
 def _window_on_full(monkeypatch):
@@ -186,6 +226,18 @@ def test_rows_past_the_groups_are_never_read(monkeypatch):
     """A grouped product leaves the rows past its groups undefined (on the
     TPU they hold whatever was in memory): NaN there must reach neither
     the layer's output nor any gradient."""
+    check_rows_past_the_groups(monkeypatch)
+
+
+@pytest.mark.parametrize("moe_fields, zeroed, share", EDGE_ROUTING)
+def test_rows_past_the_groups_are_never_read_on_edge_routing(moe_fields, zeroed, share, monkeypatch):
+    check_rows_past_the_groups(monkeypatch, moe_fields, zeroed, share)
+
+
+def check_rows_past_the_groups(monkeypatch, moe_fields=None, zeroed=False, share=None):
+    """Poison the rows past the groups with NaN, in both passes of every
+    grouped product, and check the layer's value and gradients are finite
+    (and, where given, the held share the layer reads)."""
     import repro.models.moe as moe
 
     grouped = jax.lax.ragged_dot
@@ -208,17 +260,71 @@ def test_rows_past_the_groups_are_never_read(monkeypatch):
 
     poisoned.defvjp(fwd, bwd)
     monkeypatch.setattr(moe.jax.lax, "ragged_dot", poisoned)
-    cfg = program_config(small())
+    cfg = program_config(small(**(moe_fields or {})))
     p = init_moe(jax.random.key(5), cfg, jnp.float32)
+    if zeroed:
+        p = dict(p, router=jax.tree.map(jnp.zeros_like, p["router"]))
     x = jax.random.normal(jax.random.key(6), (2, 24, 64), jnp.float32)
 
     def f(p, x):
-        y, aux, _ = apply_moe_held(cfg, p, x)
-        return jnp.sum(y * y) + aux
+        y, aux, counters = apply_moe_held(cfg, p, x)
+        return jnp.sum(y * y) + aux, counters
 
-    value, grads = jax.jit(jax.value_and_grad(f, argnums=(0, 1)))(p, x)
+    (value, counters), grads = jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))(p, x)
     assert bool(jnp.isfinite(value))
     assert all(bool(jnp.all(jnp.isfinite(g))) for g in jax.tree.leaves(grads))
+    if share is not None:
+        assert float(counters["moe_held_share"]) == share
+
+
+def _shapes(hlo_text: str) -> dict[str, tuple[int, ...]]:
+    """Instruction name -> the dimensions of its (array) result."""
+    out = {}
+    for m in re.finditer(r"^\s*(?:ROOT\s+)?%?([^\s=]+)\s*=\s*\w+\[([\d,]*)\]", hlo_text, re.M):
+        out[m.group(1)] = tuple(int(n) for n in m.group(2).split(",") if n)
+    return out
+
+
+def test_held_layer_gradient_gathers_rows_and_scatters_none():
+    """Dispatch, combine and both transposes are gathers of whole rows: in
+    the compiled gradient of the layer (bfloat16, rematerialised as in the
+    model) every scatter writes single values (the experts' counts, the
+    top-k weights), and every gather of rows of the model's width is the
+    router's, in the forward pass and the backward."""
+    if str(BENCH) not in sys.path:
+        sys.path.insert(0, str(BENCH))
+    from chipbench.scopes import op_scopes
+
+    cfg = program_config(dict(small(), dtype="bfloat16"))
+    d = cfg.d_model
+    p = init_moe(jax.random.key(7), cfg, jnp.bfloat16)
+    x = jax.random.normal(jax.random.key(8), (2, 512, d), jnp.bfloat16)
+    # The inner jit gives the layer's operations their named scopes as the
+    # model's scan over layers does: at the top of a grad, jvp would wrap
+    # them into a name that op_scopes does not read.
+    layer = jax.checkpoint(jax.jit(partial(apply_moe_held, cfg)))
+
+    def f(p, x):
+        y, aux, _ = layer(p, x)
+        return jnp.sum(y.astype(jnp.float32) ** 2) + aux
+
+    hlo = jax.jit(jax.grad(f, argnums=(0, 1))).lower(p, x).compile().as_text()
+    shapes, named = _shapes(hlo), op_scopes(hlo)
+    scatters = re.findall(
+        r"%?([^\s=]+)\s*=\s*\S+ scatter\([^,]+, [^,]+, %?([^\s,)]+)\).*?update_window_dims=\{([\d,]*)\}", hlo
+    )
+    assert scatters  # the experts' counts and the top-k weights' transpose
+    for name, updates, dims in scatters:
+        window = [shapes[updates][int(i)] for i in dims.split(",") if i]
+        assert math.prod(window) == 1, (name, shapes[updates], dims)
+    rows = [
+        (name, op) for name, op in re.findall(
+            r"%?([^\s=]+)\s*=\s*\S+ gather\((?:.*?op_name=\"([^\"]*)\")?", hlo
+        ) if len(shapes[name]) > 1 and shapes[name][-1] == d
+    ]
+    assert {"transpose(" in op for _, op in rows} == {False, True}
+    for name, op in rows:
+        assert named.get(name) == "moe.route", op
 
 
 @pytest.mark.parametrize("key_block", [2048, 16], ids=["whole", "split"])
