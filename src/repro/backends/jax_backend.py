@@ -1,8 +1,9 @@
 """``jax`` backend — interpret location programs on a JAX host device mesh.
 
 Each SWIRL location is pinned to a JAX device (round-robin over the host
-mesh, or an explicit ``devices=`` option).  The compiled artifact then
-interprets the per-location program IR deterministically:
+mesh, or an explicit ``devices=`` option).  Compiling plans the
+deterministic reducer's firing order over the per-location program IR once
+(:func:`_plan`); every run replays it:
 
 * an enabled ``ExecOp`` runs the step function with its inputs resident on
   the leader location's device and replicates ``Out^D(s)`` onto every
@@ -23,6 +24,7 @@ from __future__ import annotations
 
 import time
 import weakref
+from dataclasses import dataclass, field
 from typing import Any, Mapping
 
 from repro.core.compile import StepMeta
@@ -67,37 +69,44 @@ def _buffer_ptrs(x: Any) -> frozenset[int]:
     return frozenset()
 
 
-def _plan_segments(program, *, min_len: int = 2) -> dict[int, list]:
-    """Partition the deterministic exec firing order into fusable runs.
+def _plan(
+    program: ExecProgram, *, fuse: bool
+) -> tuple[tuple, WorkflowSystem | None]:
+    """The deterministic reducer's firing order, simulated once.
 
-    The reducer's firing order depends only on cursor states and data
-    *names*, never on payload values, so it can be replayed statically:
-    simulate the run loop (drain comms, fire the lowest-named enabled
-    exec) without calling any step body and record where straight-line
-    EXEC runs break — at a COMM boundary, or when the leader location
-    changes (a fused program runs on one device).  Returns
-    ``{start_exec_index: [(ExecOp, picks), ...]}`` for every run of at
-    least ``min_len`` ops — the picks are recorded at plan time so the
-    runtime replays cursor completions directly instead of re-scanning
-    enabledness per op; the runtime counts fired execs and swaps in the
-    jitted segment when the counter hits a start index.
+    The order depends only on cursor states and data *names*, never on
+    payload values, so it is fixed once the program is lowered: drain the
+    enabled COMMs (locations scanned in sorted order), then fire the
+    lowest-named enabled exec, until nothing is enabled.  No step body
+    runs.  Returns ``(entries, remaining)`` where each entry is
+    ``("comm", SendOp)``, ``("exec", ExecOp, locations)`` — the locations
+    of ``M(s)`` that store ``Out^D(s)`` — or, when ``fuse``,
+    ``("segment", ((ExecOp, locations), ...))`` for a straight-line run
+    of at least two execs with no COMM between them and one leader (a
+    fused program runs on one device).  ``remaining`` is the unreduced
+    system, or ``None`` when the program terminates.
     """
     cursors = {lp.location: Cursor(lp) for lp in program.programs}
     data = {lp.location: set(lp.data) for lp in program.programs}
     order = sorted(cursors)
-    seq: list = []
-    breaks: set[int] = set()
+    entries: list = []
+    run: list = []  # the straight-line exec run being collected
+
+    def close_run() -> None:
+        if fuse and len(run) >= 2:
+            entries.append(("segment", tuple(run)))
+        else:
+            entries.extend(("exec", op, locs) for op, locs in run)
+        run.clear()
+
     while True:
-        comm_fired = False
-        while True:
-            hit = first_enabled_comm(cursors, data, order)
-            if hit is None:
-                break
+        while (hit := first_enabled_comm(cursors, data, order)) is not None:
             op, src, i, j = hit
             cursors[src].complete(i)
             cursors[op.dst].complete(j)
             data[op.dst].add(op.data)
-            comm_fired = True
+            close_run()
+            entries.append(("comm", op))
         execs = sorted(
             enabled_exec_picks(cursors, data, order),
             key=lambda pair: pair[0].step,
@@ -105,22 +114,19 @@ def _plan_segments(program, *, min_len: int = 2) -> dict[int, list]:
         if not execs:
             break
         op, picks = execs[0]
-        if (
-            not seq
-            or comm_fired
-            or min(op.locations) != min(seq[-1][0].locations)
-        ):
-            breaks.add(len(seq))
-        seq.append((op, picks))
+        if run and min(op.locations) != min(run[-1][0].locations):
+            close_run()
+        run.append((op, tuple(loc for loc, _ in picks)))
         for loc, i in picks:
             cursors[loc].complete(i)
             data[loc].update(op.outputs)
-    segments: dict[int, list] = {}
-    starts = sorted(breaks) + [len(seq)]
-    for a, b in zip(starts, starts[1:]):
-        if b - a >= min_len:
-            segments[a] = seq[a:b]
-    return segments
+    close_run()
+    if all(c.finished() for c in cursors.values()):
+        return tuple(entries), None
+    return tuple(entries), program.remaining_system(
+        {l: c.done_flags() for l, c in cursors.items()},
+        {l: frozenset(d) for l, d in data.items()},
+    )
 
 
 class _FusedSegment:
@@ -135,11 +141,10 @@ class _FusedSegment:
     that no other store entry shares (see ``JaxMeshProgram.run``).
     """
 
-    def __init__(self, acts: list, steps: Mapping[str, StepMeta]):
+    def __init__(self, acts: tuple, steps: Mapping[str, StepMeta]):
         import jax
 
-        self.acts = acts  # [(ExecOp, picks), ...] in firing order
-        self.ops = ops = [op for op, _ in acts]
+        self.ops = ops = [op for op, _ in acts]  # in firing order
         self.leader = min(ops[0].locations)
         produced: set[str] = set()
         ext: list[str] = []
@@ -181,7 +186,21 @@ class _FusedSegment:
         return self.fn.lower(*self.arg_shapes).compile().as_text()
 
 
+@dataclass
 class JaxMeshProgram(BackendProgram):
+    #: The firing order :func:`_plan` made at compile; every run replays it.
+    plan: tuple = ()
+    #: The system the plan leaves unreduced, or ``None`` if it terminates.
+    remaining: WorkflowSystem | None = None
+    #: Whether ``_plan`` fused (runs then report ``stats["fused"]``).
+    fuse: bool = False
+    #: Plan entry index -> its ``_FusedSegment``, or ``"eager"`` once it
+    #: must stay interpreted; jitted segments live across ``run()`` calls
+    #: so repeat runs hit XLA's cache.
+    _segs: dict = field(
+        default_factory=dict, init=False, repr=False, compare=False
+    )
+
     def _device_map(self) -> dict[str, Any]:
         import jax
 
@@ -232,9 +251,9 @@ class JaxMeshProgram(BackendProgram):
             "comm_bytes": 0,
             "devices": {l: str(d) for l, d in device_of.items()},
         }
-        # Uniform fault policy: the deterministic reducer guards each step
-        # fire with the shared timeout + retry helper and checks the run
-        # deadline once per reduction round.
+        # Uniform fault policy: each step fire goes through the shared
+        # timeout + retry helper, and the run deadline is checked after
+        # each exec or fused segment of the planned order.
         policy = self.options.get("policy")
         guard = None
         deadline = Deadline(None)
@@ -248,6 +267,17 @@ class JaxMeshProgram(BackendProgram):
             )
             deadline = Deadline(policy.deadline_s)
             stats["policy"] = {"retries": 0, "timeouts": 0}
+        if self.fuse:
+            stats["fused"] = {
+                "segments_planned": sum(
+                    e[0] == "segment" for e in self.plan
+                ),
+                "fused_calls": 0,
+                "fused_execs": 0,
+                "fallbacks": 0,
+                "donated": 0,
+                "locations": {},
+            }
 
         # Buffers this run allocated itself.  Only these may be donated:
         # a caller's initial payload, or an array a step body returned
@@ -277,50 +307,31 @@ class JaxMeshProgram(BackendProgram):
         for (loc, d), v in (initial_payloads or {}).items():
             payloads[(loc, d)] = place(loc, v)
 
-        cursors = {
-            lp.location: Cursor(lp) for lp in self.program.programs
-        }
-        data = {lp.location: set(lp.data) for lp in self.program.programs}
-        order = sorted(cursors)
-
-        def fire_one_comm() -> bool:
-            hit = first_enabled_comm(cursors, data, order)
-            if hit is None:
-                return False
-            op, src, i, j = hit
-            cursors[src].complete(i)
-            cursors[op.dst].complete(j)
-            data[op.dst].add(op.data)
-            payload = payloads[(op.src, op.data)]
-            with comm_span(recorder, op, payload):
-                payloads[(op.dst, op.data)] = place(
-                    op.dst, payload, comm=True
+        def store(op, locations, out: dict, on: Any = None) -> None:
+            """Store ``Out^D(s)`` at every location of ``M(s)``; outputs
+            already on device ``on`` (a fused call's) are not placed."""
+            missing = set(op.outputs) - set(out)
+            if missing:
+                raise RuntimeError(
+                    f"step {op.step!r} did not produce {sorted(missing)}"
                 )
-            stats["comms"] += 1
-            return True
+            for loc in locations:
+                for d in op.outputs:
+                    payloads[(loc, d)] = (
+                        out[d] if device_of[loc] is on else place(loc, out[d])
+                    )
+            stats["execs"] += 1
 
-        # Fused location programs: straight-line EXEC runs become single
-        # jitted calls (segmented at COMM boundaries).  A fault policy
-        # guard wraps individual step fires, which a fused call cannot
-        # honour, so fusion is skipped when a guard is active.
-        fuse = bool(self.options.get("fuse")) and guard is None
-        if fuse and not hasattr(self, "_segments"):
-            # Plan once per compiled program; jitted segment functions
-            # live across run() calls so repeat runs hit XLA's cache.
-            self._segments = _plan_segments(self.program)
-            self._seg_cache: dict[int, Any] = {}
-        segments = self._segments if fuse else {}
-        seg_cache = self._seg_cache if fuse else {}
-        if fuse:
-            stats["fused"] = {
-                "segments_planned": len(segments),
-                "fused_calls": 0,
-                "fused_execs": 0,
-                "fallbacks": 0,
-                "donated": 0,
-                "locations": {},
-            }
-        exec_count = 0
+        def fire(op, locations) -> None:
+            leader = min(op.locations)
+            inputs = {d: payloads[(leader, d)] for d in op.inputs}
+            fn = self.steps[op.step].fn
+            with exec_span(recorder, op, leader):
+                out = (
+                    fn(inputs) if guard is None
+                    else guard.fire(op.step, lambda: fn(inputs))
+                )
+            store(op, locations, out)
 
         def donatable(seg: _FusedSegment, env: dict) -> dict[str, Any]:
             """Inputs whose buffers the segment may consume in place."""
@@ -340,25 +351,23 @@ class JaxMeshProgram(BackendProgram):
                 if all(shared.get(ptr, 0) == 1 for ptr in _buffer_ptrs(v))
             }
 
-        def run_segment(start: int) -> bool:
+        def run_segment(index: int, acts: tuple) -> bool:
             """Fire a whole planned segment as one jitted call.
 
             Returns False (after caching the verdict) when the segment
             must stay interpreted — non-array inputs, or a step body
-            that does not trace; the caller then falls through to the
-            op-by-op path for every op in the run.  An error while
-            compiling or running a segment that did trace propagates.
+            that does not trace; the caller then fires its execs one by
+            one.  An error while compiling or running a segment that did
+            trace propagates.
             """
-            seg = seg_cache.get(start)
+            seg = self._segs.get(index)
             if seg == "eager":
                 return False
-            acts = segments[start]
             if seg is None:
-                seg = _FusedSegment(acts, self.steps)
-                seg_cache[start] = seg
+                seg = self._segs[index] = _FusedSegment(acts, self.steps)
             env = {d: payloads[(seg.leader, d)] for d in seg.ext}
             if not all(_is_array(v) for v in env.values()):
-                seg_cache[start] = "eager"
+                self._segs[index] = "eager"
                 stats["fused"]["fallbacks"] += 1
                 return False
             donated = donatable(seg, env)
@@ -367,7 +376,7 @@ class JaxMeshProgram(BackendProgram):
                 try:
                     seg.fn.trace(donated, kept)
                 except Exception:  # a step body that does not trace
-                    seg_cache[start] = "eager"
+                    self._segs[index] = "eager"
                     stats["fused"]["fallbacks"] += 1
                     return False
                 seg.traced = True
@@ -390,87 +399,40 @@ class JaxMeshProgram(BackendProgram):
             loc_stats["execs"] += len(acts)
             stats["fused"]["fused_calls"] += 1
             stats["fused"]["fused_execs"] += len(acts)
-            # Replay the run's cursor/data effects from the recorded
-            # plan — the values came from the fused call, the
-            # bookkeeping (and the replication of Out^D(s) onto every
-            # D_i) is unchanged.  Outputs already live on the leader's
-            # device, so placement only pays for genuinely remote
-            # locations.
-            leader_dev = device_of[seg.leader]
-            for op, picks in acts:
-                missing = set(op.outputs) - set(out)
-                if missing:
-                    raise RuntimeError(
-                        f"step {op.step!r} did not produce "
-                        f"{sorted(missing)}"
-                    )
-                for loc, i in picks:
-                    cursors[loc].complete(i)
-                    data[loc].update(op.outputs)
-                    for d in op.outputs:
-                        payloads[(loc, d)] = (
-                            out[d]
-                            if device_of[loc] is leader_dev
-                            else place(loc, out[d])
-                        )
-                stats["execs"] += 1
+            # The values came from the fused call; the replication of
+            # Out^D(s) onto every D_i is unchanged, and placement only
+            # pays for locations on another device than the leader's.
+            for op, locations in acts:
+                store(op, locations, out, on=device_of[seg.leader])
             return True
 
-        max_rounds = int(self.options.get("max_rounds", 1_000_000))
-        for _ in range(max_rounds):
-            deadline.check()
-            progressed = False
-            # Drain communications first (they are τ — silent, confluent).
-            while fire_one_comm():
-                progressed = True
-            if fuse and exec_count in segments:
-                if run_segment(exec_count):
-                    exec_count += len(segments[exec_count])
-                    progressed = True
-                    continue
-            # Deterministic firing order: lowest step name first.
-            execs = sorted(
-                enabled_exec_picks(cursors, data, order),
-                key=lambda pair: pair[0].step,
-            )
-            if execs:
-                op, picks = execs[0]
-                leader = min(op.locations)
-                inputs = {d: payloads[(leader, d)] for d in op.inputs}
-                fn = self.steps[op.step].fn
-                fire = (
-                    (lambda: guard.fire(op.step, lambda: fn(inputs)))
-                    if guard is not None
-                    else (lambda: fn(inputs))
-                )
-                with exec_span(recorder, op, leader):
-                    out = fire()
-                missing = set(op.outputs) - set(out)
-                if missing:
-                    raise RuntimeError(
-                        f"step {op.step!r} did not produce {sorted(missing)}"
+        deadline.check()
+        for index, entry in enumerate(self.plan):
+            kind = entry[0]
+            if kind == "comm":
+                op = entry[1]
+                payload = payloads[(op.src, op.data)]
+                with comm_span(recorder, op, payload):
+                    payloads[(op.dst, op.data)] = place(
+                        op.dst, payload, comm=True
                     )
-                for loc, i in picks:
-                    cursors[loc].complete(i)
-                    data[loc].update(op.outputs)
-                    for d in op.outputs:
-                        payloads[(loc, d)] = place(loc, out[d])
-                stats["execs"] += 1
-                exec_count += 1
-                progressed = True
-            if not progressed:
-                break
+                stats["comms"] += 1
+            elif kind == "exec":
+                fire(entry[1], entry[2])
+                deadline.check()
+            elif run_segment(index, entry[1]):
+                deadline.check()
+            else:
+                for op, locations in entry[1]:
+                    fire(op, locations)
+                    deadline.check()
 
         if guard is not None:
             stats["policy"] = guard.counts()
-        if not all(c.finished() for c in cursors.values()):
-            remaining = self.program.remaining_system(
-                {l: c.done_flags() for l, c in cursors.items()},
-                {l: frozenset(d) for l, d in data.items()},
-            )
+        if self.remaining is not None:
             raise RuntimeError(
                 "jax backend: workflow did not terminate; remaining:\n"
-                + remaining.pretty()
+                + self.remaining.pretty()
             )
         result: dict[str, dict[str, Any]] = {
             loc: {} for loc in self.program.locations()
@@ -489,15 +451,13 @@ class JaxMeshProgram(BackendProgram):
     def segment_hlo(self) -> dict[int, str]:
         """Compiled HLO text of every fused segment that has run.
 
-        Keyed by the segment's first exec index in the firing order;
-        shows which kernels the compiler emitted for the device the
-        segment ran on (a Pallas kernel on a TPU is a
-        ``tpu_custom_call``).
+        Keyed by the segment's index in the plan; shows which kernels
+        the compiler emitted for the device the segment ran on (a Pallas
+        kernel on a TPU is a ``tpu_custom_call``).
         """
-        cache = getattr(self, "_seg_cache", {})
         return {
-            start: seg.hlo()
-            for start, seg in sorted(cache.items())
+            index: seg.hlo()
+            for index, seg in sorted(self._segs.items())
             if isinstance(seg, _FusedSegment) and seg.calls
         }
 
@@ -508,7 +468,7 @@ class JaxBackend(Backend):
 
     def known_options(self) -> frozenset[str]:
         return super().known_options() | frozenset(
-            {"devices", "platform", "max_rounds", "fuse"}
+            {"devices", "platform", "fuse"}
         )
 
     def compile(
@@ -517,10 +477,18 @@ class JaxBackend(Backend):
         steps: Mapping[str, StepMeta],
         options: Mapping[str, Any],
     ) -> JaxMeshProgram:
+        program = self.lower(program, options)
+        # A fault policy's guard wraps individual step fires, which a
+        # fused call cannot honour, so a policy turns fusion off.
+        fuse = bool(options.get("fuse")) and options.get("policy") is None
+        plan, remaining = _plan(program, fuse=fuse)
         return JaxMeshProgram(
-            program=self.lower(program, options),
+            program=program,
             steps=dict(steps),
             options=dict(options),
+            plan=plan,
+            remaining=remaining,
+            fuse=fuse,
         )
 
 

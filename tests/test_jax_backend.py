@@ -1,5 +1,6 @@
-"""``jax`` backend fused segments: when they go eager and when they fail;
-the bytes its COMMs copy between devices."""
+"""``jax`` backend: the firing order planned at compile and replayed by
+every run; fused segments, when they go eager and when they fail; the
+bytes its COMMs copy between devices."""
 
 import json
 import os
@@ -13,6 +14,7 @@ import numpy as np
 import pytest
 
 from repro import swirl
+from repro.backends import jax_backend
 
 CHAIN = "<l0, {x}, exec(s1, {x} -> {y}, {l0}) . exec(s2, {y} -> {z}, {l0})>"
 
@@ -49,6 +51,7 @@ def test_runtime_error_in_traced_segment_propagates():
 _COMM_BYTES = """
 import json, jax, jax.numpy as jnp
 from repro import swirl
+from repro.backends import jax_backend
 plan = swirl.trace(
     "<a, {x}, exec(s1, {x} -> {y}, {a}) . send(y -> p, a, b)>"
     " | <b, {}, recv(p, a, b) . exec(s2, {y} -> {z}, {b})>"
@@ -83,3 +86,89 @@ def test_comm_bytes_count_only_copies_to_another_device():
     assert out["1"]["comm_bytes"] == 0
     assert out["2"]["comm_bytes"] == 1024
     assert out["1"]["bytes_moved"] == out["2"]["bytes_moved"] > 1024
+
+
+# Two fusable runs on two locations with one COMM between them.
+TWO_RUNS = (
+    "<a, {x}, exec(s1, {x} -> {y}, {a}) . exec(s2, {y} -> {z}, {a})"
+    " . send(z -> p, a, b)>"
+    " | <b, {}, recv(p, a, b) . exec(s3, {z} -> {w}, {b})"
+    " . exec(s4, {w} -> {v}, {b})>"
+)
+TWO_RUNS_STEPS = {
+    "s1": lambda i: {"y": i["x"] * 2},
+    "s2": lambda i: {"z": i["y"] + 1},
+    "s3": lambda i: {"w": i["z"] * 3},
+    "s4": lambda i: {"v": i["w"] - 1},
+}
+
+
+@pytest.mark.parametrize("fuse", [False, True])
+def test_run_replays_the_plan_without_scanning_cursors(monkeypatch, fuse):
+    """Once compiled, a run needs neither enabledness predicate: it
+    replays the order planned at compile, fused or not."""
+    exe = swirl.trace(TWO_RUNS).lower("jax", fuse=fuse).compile(TWO_RUNS_STEPS)
+    kinds = [entry[0] for entry in exe.program.plan]
+    assert kinds == (
+        ["segment", "comm", "segment"] if fuse
+        else ["exec", "exec", "comm", "exec", "exec"]
+    )
+
+    def scan(*args, **kwargs):
+        raise AssertionError("a run rescanned the cursors")
+
+    monkeypatch.setattr(jax_backend, "first_enabled_comm", scan)
+    monkeypatch.setattr(jax_backend, "enabled_exec_picks", scan)
+    x = jnp.ones(4, jnp.float32)
+    res = exe.run(initial_payloads={("a", "x"): x})
+    y = x * 2
+    z = y + 1
+    w = z * 3
+    want = {"a": {"x": x, "y": y, "z": z}, "b": {"z": z, "w": w, "v": w - 1}}
+    assert {loc: set(d) for loc, d in res.data.items()} == {
+        loc: set(d) for loc, d in want.items()
+    }
+    for loc, data in want.items():
+        for d, v in data.items():
+            np.testing.assert_array_equal(
+                np.asarray(res.data[loc][d]), np.asarray(v)
+            )
+    assert (res.stats["execs"], res.stats["comms"]) == (4, 1)
+    if fuse:
+        fused = res.stats["fused"]
+        assert fused["segments_planned"] == fused["fused_calls"] == 2
+        assert fused["fused_execs"] == 4
+        assert fused["fallbacks"] == 0
+        assert fused["locations"] == {
+            "a": {"calls": 1, "execs": 2}, "b": {"calls": 1, "execs": 2},
+        }
+    else:
+        assert "fused" not in res.stats
+
+
+def test_run_that_cannot_finish_fires_what_it_can_then_raises():
+    """A recv with no send: the planned steps fire, then the run raises
+    with the system the plan left unreduced."""
+    fired = []
+
+    def s1(inputs):
+        fired.append("s1")
+        return {"y": inputs["x"] + 1}
+
+    exe = swirl.trace(
+        "<a, {x}, exec(s1, {x} -> {y}, {a})>"
+        " | <b, {}, recv(p, a, b) . exec(s2, {y} -> {z}, {b})>"
+    ).lower("jax").compile({"s1": s1, "s2": lambda i: {"z": i["y"]}})
+    with pytest.raises(RuntimeError, match="did not terminate") as err:
+        exe.run(initial_payloads={("a", "x"): jnp.ones(2)})
+    assert fired == ["s1"]
+    assert "recv(p,a,b).exec(s2,{y}->{z},{b})" in str(err.value)
+    assert exe.program.remaining.pretty() in str(err.value)
+
+
+def test_max_rounds_is_not_an_option():
+    with pytest.raises(TypeError, match="max_rounds"):
+        swirl.trace(CHAIN).lower("jax", max_rounds=10)
+    assert jax_backend.JaxBackend().known_options() == {
+        "schedule", "trace", "policy", "devices", "platform", "fuse",
+    }
